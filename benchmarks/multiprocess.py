@@ -308,6 +308,10 @@ def _pin_host_parallelism() -> None:
     first ``import jax``): the USF runtime's streams are the only source
     of parallelism, so a slot grant maps 1:1 onto a busy core and the
     free-running baseline oversubscribes exactly N_PROCS x."""
+    # these workers measure host scheduling and are regression guards,
+    # not device numbers: keep them on the CPU, so that several of them
+    # (and `make check` on a TPU host) never contend for a chip
+    os.environ["JAX_PLATFORMS"] = "cpu"
     for var in ("OPENBLAS_NUM_THREADS", "OMP_NUM_THREADS",
                 "MKL_NUM_THREADS"):
         os.environ.setdefault(var, "1")

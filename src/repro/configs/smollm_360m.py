@@ -1,5 +1,9 @@
 """smollm-360m [dense] — 32L d_model=960 15H (GQA kv=5) d_ff=2560
-vocab=49152, llama-arch small. [hf:HuggingFaceTB/SmolLM-135M; hf]"""
+vocab=49152, llama-arch small. Source: HuggingFaceTB/SmolLM-360M
+``config.json`` (``rms_norm_eps`` 1e-5, ``rope_theta`` 10000).
+
+Tied embeddings (``tie_word_embeddings: true`` upstream) are not modelled:
+the untied unembedding adds about 47M parameters (960 x 49152)."""
 
 import dataclasses
 
@@ -16,6 +20,7 @@ CONFIG = ArchConfig(
     vocab=49152,
     head_dim=64,
     rope_theta=10_000.0,
+    norm_eps=1e-5,
     mlp_act="silu",
 )
 

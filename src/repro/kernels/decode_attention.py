@@ -5,6 +5,11 @@ into VMEM scratch (running m/l/acc per q-head-group) — flash-decoding
 adapted to the TPU's sequential minor grid axis instead of GPU thread-block
 reductions. Validity is positional (cache slots carry absolute positions:
 ring buffers for SWA/local attention come for free).
+
+TPU block layout: a block's last two dims must be multiples of (8, 128)
+or span the array, so the slot positions ride as ``[B, 1, W]`` (block
+``(1, 1, blk_k)``) and the per-row query positions are a scalar-prefetch
+operand in SMEM rather than a ``(1,)`` VMEM block.
 """
 
 from __future__ import annotations
@@ -15,17 +20,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1.0e30
 
 
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
-
-
-def _kernel(q_ref, k_ref, v_ref, cpos_ref, qpos_ref, o_ref,
+def _kernel(qpos_ref, q_ref, k_ref, v_ref, cpos_ref, o_ref,
             m_scr, l_scr, acc_scr, *, scale: float,
             window: Optional[int], blk_k: int, G: int):
     ki = pl.program_id(2)
@@ -41,15 +41,15 @@ def _kernel(q_ref, k_ref, v_ref, cpos_ref, qpos_ref, o_ref,
     k = k_ref[0, 0].astype(jnp.float32)                # [blk_k, D]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [G, blk_k]
-    cpos = cpos_ref[0]                                  # [blk_k] int32
-    qpos = qpos_ref[0]                                  # [] int32
+    cpos = cpos_ref[0]                                  # [1, blk_k] int32
+    qpos = qpos_ref[pl.program_id(0)]                   # [] int32 (SMEM)
     valid = jnp.logical_and(cpos >= 0, cpos <= qpos)
     if window is not None:
         valid = jnp.logical_and(valid, qpos - cpos < window)
-    s = jnp.where(valid[None, :], s, NEG)
+    s = jnp.where(valid, s, NEG)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.where(valid[None, :], jnp.exp(s - m_new[:, None]), 0.0)
+    p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
     v = v_ref[0, 0].astype(jnp.float32)
@@ -90,24 +90,30 @@ def flash_decode(
     nk = Wp // blk_k
     qg = q.reshape(B, KV, G, D)
 
+    # index maps also receive the scalar-prefetch ref (unused here)
     out = pl.pallas_call(
         functools.partial(_kernel, scale=D ** -0.5, window=window,
                           blk_k=blk_k, G=G),
-        grid=(B, KV, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, blk_k), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1,), lambda b, h, j: (b,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, KV, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, D), lambda b, h, j, qp: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, blk_k, D),
+                             lambda b, h, j, qp: (b, h, j, 0)),
+                pl.BlockSpec((1, 1, blk_k, D),
+                             lambda b, h, j, qp: (b, h, j, 0)),
+                pl.BlockSpec((1, 1, blk_k), lambda b, h, j, qp: (b, 0, j)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, D),
+                                   lambda b, h, j, qp: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G,), jnp.float32),
+                pltpu.VMEM((G, D), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        scratch_shapes=[
-            _vmem((G,), jnp.float32),
-            _vmem((G,), jnp.float32),
-            _vmem((G, D), jnp.float32),
-        ],
         interpret=interpret,
-    )(qg, k_cache, v_cache, cache_pos, q_pos)
+    )(q_pos.astype(jnp.int32), qg, k_cache, v_cache, cache_pos[:, None, :])
     return out.reshape(B, H, D)

@@ -1,7 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to auto-detection: True off-TPU (this container),
-False on real TPU hardware. Model code calls these through
+``interpret`` is explicit and defaults to False: the kernels compile for
+the TPU, and a caller that wants the Pallas interpreter (CPU tests,
+``JAX_PLATFORMS=cpu``) passes ``interpret=True``. Nothing falls back to
+interpret mode on its own. Model code calls these through
 ``cfg.attn_backend="pallas"`` etc.; layouts are adapted here
 ([B,S,H,D] model convention -> [B,H,S,D] kernel convention).
 """
@@ -21,50 +23,41 @@ from repro.kernels import rglru_scan as _rg
 from repro.kernels import ssd_scan as _ssd
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: bool = False):
     """Model layout: q [B,S,H,D]; k,v [B,T,KV,D] -> [B,S,H,D]."""
-    it = _interpret_default() if interpret is None else interpret
     qT = jnp.swapaxes(q, 1, 2)
     kT = jnp.swapaxes(k, 1, 2)
     vT = jnp.swapaxes(v, 1, 2)
     o = _fa.flash_attention_fwd(qT, kT, vT, causal=causal, window=window,
-                                interpret=it)
+                                interpret=interpret)
     return jnp.swapaxes(o, 1, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def flash_decode(q, k_cache, v_cache, cache_pos, q_pos, *,
                  window: Optional[int] = None,
-                 interpret: Optional[bool] = None):
+                 interpret: bool = False):
     """q [B,H,D]; caches [B,W,KV,D] (model layout) -> [B,H,D]."""
-    it = _interpret_default() if interpret is None else interpret
     kT = jnp.swapaxes(k_cache, 1, 2)
     vT = jnp.swapaxes(v_cache, 1, 2)
     return _dec.flash_decode(q, kT, vT, cache_pos, q_pos, window=window,
-                             interpret=it)
+                             interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 256,
-             interpret: Optional[bool] = None):
-    it = _interpret_default() if interpret is None else interpret
-    return _ssd.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=it)
+             interpret: bool = False):
+    return _ssd.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def rglru(a, b, h0, *, interpret: Optional[bool] = None):
-    it = _interpret_default() if interpret is None else interpret
-    return _rg.rglru_scan_kernel(a, b, h0, interpret=it)
+def rglru(a, b, h0, *, interpret: bool = False):
+    return _rg.rglru_scan_kernel(a, b, h0, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def moe_gmm(x, w, *, interpret: Optional[bool] = None):
-    it = _interpret_default() if interpret is None else interpret
-    return _gmm.moe_gmm(x, w, interpret=it)
+def moe_gmm(x, w, *, interpret: bool = False):
+    return _gmm.moe_gmm(x, w, interpret=interpret)

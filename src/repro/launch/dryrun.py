@@ -214,8 +214,6 @@ def _compile(cfg, shape, mesh, *, microbatches=1, rules=None,
 
 def _cost(compiled) -> dict:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax<=0.4.x: one dict per computation
-        ca = ca[0] if ca else {}
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),

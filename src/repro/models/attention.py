@@ -18,6 +18,7 @@ and recurrentgemma).
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -34,11 +35,20 @@ _NEG = -1.0e30
 # --------------------------------------------------------------------------- #
 def attn_specs(cfg) -> dict:
     d, H, KV, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    # "fan_in" divides by shape[-2], but the fan-in of [d, H, hd] is d and
+    # that of [H, hd, d] is H*hd: rescale to 1/sqrt(true fan-in). Without
+    # it q.k/sqrt(hd) has std ~sqrt(d/H) and softmax becomes a near-argmax
+    # that amplifies rounding differences layer after layer.
+    q_in, o_in = math.sqrt(H / d), 1.0 / math.sqrt(H)
     specs = {
-        "wq": ParamSpec((d, H, hd), ("embed", "heads", "head_dim"), init="fan_in"),
-        "wk": ParamSpec((d, KV, hd), ("embed", "kv_heads", "head_dim"), init="fan_in"),
-        "wv": ParamSpec((d, KV, hd), ("embed", "kv_heads", "head_dim"), init="fan_in"),
-        "wo": ParamSpec((H, hd, d), ("heads", "head_dim", "embed"), init="fan_in"),
+        "wq": ParamSpec((d, H, hd), ("embed", "heads", "head_dim"),
+                        init="fan_in", scale=q_in),
+        "wk": ParamSpec((d, KV, hd), ("embed", "kv_heads", "head_dim"),
+                        init="fan_in", scale=math.sqrt(KV / d)),
+        "wv": ParamSpec((d, KV, hd), ("embed", "kv_heads", "head_dim"),
+                        init="fan_in", scale=math.sqrt(KV / d)),
+        "wo": ParamSpec((H, hd, d), ("heads", "head_dim", "embed"),
+                        init="fan_in", scale=o_in),
     }
     if cfg.qkv_bias:
         specs["bq"] = ParamSpec((H, hd), ("heads", "head_dim"), init="zeros")

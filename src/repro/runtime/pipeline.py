@@ -18,7 +18,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -64,12 +63,12 @@ def gpipe_forward(
         (buf, outs), _ = jax.lax.scan(tick, (buf, outs), jnp.arange(ticks))
         return outs
 
-    sm = shard_map(
+    sm = jax.shard_map(
         per_rank,
         mesh=mesh,
         in_specs=(P(axis), P(None)),
         out_specs=P(axis),  # each rank emits its view; stage n-1 is truth
-        check_rep=False,
+        check_vma=False,
     )
     all_outs = sm(stage_params, microbatches)
     # out has a leading pipe dim folded into axis 0 of outs per rank:

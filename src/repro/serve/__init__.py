@@ -1,3 +1,4 @@
-from repro.serve.engine import InferenceServer, Gateway, Request
-
-__all__ = ["InferenceServer", "Gateway", "Request"]
+"""Serving: the in-process engine (``serve.engine``) and its multi-process
+twin (``serve.multiproc``). Import the submodule you need; the package
+imports nothing, so a gateway process that only needs ``multiproc`` stays
+off JAX."""

@@ -20,6 +20,7 @@ with work-conserving borrowing when the gateway is idle.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 from typing import Any, Optional
@@ -41,6 +42,17 @@ from repro.runtime.sharding import Sharder
 from repro.train.step import make_serve_step
 
 _RID = itertools.count()
+
+
+def _greedy_reference(model, sharder, params, tokens, positions):
+    """Full-sequence forward (``LM.forward``, the plain reference of the
+    decode path): per position, the argmax and the gap between the top two
+    logits, both for row 0."""
+    logits, _ = model.forward(params, {"tokens": tokens,
+                                       "positions": positions}, sharder)
+    lg = logits[0].astype(jnp.float32)
+    top2 = jax.lax.top_k(lg, 2)[0]
+    return jnp.argmax(lg, axis=-1), top2[:, 0] - top2[:, 1]
 
 
 @dataclasses.dataclass
@@ -98,6 +110,8 @@ class InferenceServer:
             # or elastic shrink parks this worker within ~one engine step
             # even when the batch never drains (docs/PREEMPTION.md tier 3)
             self._step = wrap_jit(self._step, runtime=usf)
+        self._reference = jax.jit(
+            functools.partial(_greedy_reference, self.model, self.sharder))
         self._task = None
         self._stop = False
         self.served = 0
@@ -167,6 +181,35 @@ class InferenceServer:
     def stop(self) -> None:
         self._stop = True
         self.queue.put(None)  # wake the worker
+
+    def reference_check(self, prompt: list[int], served: list[int],
+                        margin: float) -> dict:
+        """Check served tokens against ``LM.forward`` with the same params,
+        teacher-forced over ``prompt + served``.
+
+        Decode (through the KV cache) and the full forward round
+        differently, so a position counts only where the reference's top
+        two logits are more than ``margin`` apart; there its argmax must
+        equal the served token. The sequence is padded to ``max_len``
+        (causal attention: the pad never reaches a checked position), so
+        every request shares one compiled reference."""
+        seq = list(prompt) + list(served)
+        if not prompt or not served or len(seq) > self.max_len:
+            raise ValueError(f"need a prompt and 1..max_len={self.max_len} "
+                             f"tokens in all, got {len(prompt)}+{len(served)}")
+        toks = np.zeros((1, self.max_len), np.int32)
+        toks[0, :len(seq) - 1] = seq[:-1]
+        pos = np.arange(self.max_len, dtype=np.int32)[None]
+        if self.cfg.mrope_sections is not None:
+            pos = np.broadcast_to(pos, (3, 1, self.max_len))
+        best, gap = self._reference(self.params, jnp.asarray(toks),
+                                    jnp.asarray(pos))
+        at = slice(len(prompt) - 1, len(seq) - 1)
+        best = np.asarray(best)[at]
+        decided = np.asarray(gap)[at] > margin
+        wrong = decided & (best != np.asarray(served))
+        return {"positions": len(served), "checked": int(decided.sum()),
+                "mismatches": int(wrong.sum()), "ok": not wrong.any()}
 
     # ------------------------------------------------------------------ #
     def _serve_loop(self) -> None:
@@ -243,7 +286,7 @@ class Gateway:
                                 share=share)
         self.responses: list[dict] = []
 
-    def _check_servers(self) -> None:
+    def check_servers(self) -> None:
         """A dead server worker would leave fanned-out requests pending
         forever: surface its task exception to the caller instead."""
         for s in self.servers:
@@ -278,7 +321,7 @@ class Gateway:
                     poll = min(poll, max(deadline - time.monotonic(), 0.0))
                 if r.done.wait(timeout=poll):
                     break
-                self._check_servers()
+                self.check_servers()
                 if deadline is not None and time.monotonic() >= deadline:
                     raise TimeoutError(
                         f"gateway fan-out exceeded {timeout}s "
@@ -287,6 +330,8 @@ class Gateway:
         rec = {
             "latency": time.monotonic() - t0,
             "per_server": {s.name: r.latency for s, r in zip(self.servers, reqs)},
+            "outputs": {s.name: list(r.output)
+                        for s, r in zip(self.servers, reqs)},
         }
         if slo is not None:
             rec["slo"] = slo

@@ -31,10 +31,19 @@ way a dead server's node lease is reclaimed by the broker (its slots
 flow to the survivors) and a dead broker degrades every server to
 free-running — then heals: the server-side ``BrokerClient`` reconnects
 with backoff once a broker is back on the rendezvous path.
+
+Chips: a chip belongs to one process at a time. On a TPU host the gateway
+gives server *i* chip *i*, bound through libtpu's per-process visibility
+settings (``chip_env``) in the child before it touches JAX, and the child
+refuses to serve unless it sees exactly that one TPU device. Asking for
+more servers than the host has chips raises at construction instead of
+hanging on the chip's lock. The gateway and broker stay off JAX: this
+module imports none of it, and chips are counted from PCI and /dev.
 """
 
 from __future__ import annotations
 
+import glob
 import multiprocessing as mp
 import os
 import queue as queue_mod
@@ -49,13 +58,72 @@ from repro.ipc import BrokerClient, NodeBroker
 _CTX = mp.get_context("spawn")
 
 
+#: PCI device ids of TPU chips (vendor 0x1ae0): v4, v5p, v5e, v6e
+_TPU_PCI_IDS = frozenset({"0x005e", "0x0062", "0x0063", "0x006f"})
+#: libtpu's per-process port for chip 0; chip i listens on base + i
+_TPU_PORT_BASE = 8476
+
+
 class ServerProcessError(RuntimeError):
     pass
 
 
+def tpu_chip_count() -> int:
+    """TPU chips this process may hand to server children, counted without
+    loading JAX or libtpu: TPU devices on PCI, capped by the chip device
+    nodes it can open (``/dev/accel*`` on v4/v5p, ``/dev/vfio/<n>`` on
+    v5e/v6e). A VM given one chip of a four-chip host sees four on PCI but
+    one node. 0 where ``JAX_PLATFORMS`` leaves the TPU out (the CPU path
+    the tests take)."""
+    platforms = os.environ.get("JAX_PLATFORMS")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    on_pci = 0
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        dev = os.path.join(os.path.dirname(vendor), "device")
+        with open(vendor) as fv, open(dev) as fd:
+            if (fv.read().strip() == "0x1ae0"
+                    and fd.read().strip() in _TPU_PCI_IDS):
+                on_pci += 1
+    nodes = (len(glob.glob("/dev/accel[0-9]*"))
+             + len(glob.glob("/dev/vfio/[0-9]*")))
+    return min(on_pci, nodes)
+
+
+def chip_env(chip: int) -> dict:
+    """libtpu settings under which a process sees only TPU chip ``chip``,
+    as a one-chip slice of its own. Per-process bounds smaller than the
+    host's let each such process load libtpu beside the others."""
+    port = _TPU_PORT_BASE + chip
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(port),
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+    }
+
+
 def _server_main(spec: dict, req_q, resp_q) -> None:
     """Child entry: one InferenceServer on its own broker-bound runtime."""
+    chip = spec.get("chip")
+    if chip is not None:
+        # before anything loads libtpu: this child sees its chip only
+        os.environ.update(chip_env(chip))
     try:
+        import jax
+
+        devices = jax.devices()
+        if chip is not None:
+            if len(devices) != 1 or devices[0].platform != "tpu":
+                raise ServerProcessError(
+                    f"bound to TPU chip {chip} but JAX sees {devices}")
+            from repro.runtime.compile_cache import enable_compile_cache
+
+            enable_compile_cache()
+        device = {"platform": devices[0].platform,
+                  "kind": devices[0].device_kind, "count": len(devices)}
+
         from repro.configs.base import get_arch, get_smoke
         from repro.core.policies import SchedCoop
         from repro.core.threads import UsfRuntime
@@ -103,11 +171,16 @@ def _server_main(spec: dict, req_q, resp_q) -> None:
             auto_ckpt=bool(spec.get("auto_ckpt", True)),
         )
         server.start()
-        resp_q.put({"ready": True, "pid": os.getpid()})
+        resp_q.put({"ready": True, "pid": os.getpid(), "device": device})
         while True:
             item = req_q.get()
             if item is None:
                 break
+            if item[0] == "check":
+                _, rid, prompt, served, margin = item
+                resp_q.put({"rid": rid, **server.reference_check(
+                    prompt, served, margin)})
+                continue
             rid, tokens, max_new = item
             req = server.submit(Request(tokens=list(tokens),
                                         max_new=int(max_new)))
@@ -146,7 +219,7 @@ class ServerProcess:
                  slots: int = 2, share: Optional[float] = None,
                  nice: int = 0, max_batch: int = 2, max_len: int = 32,
                  smoke: bool = True, heartbeat_interval: float = 0.2,
-                 auto_ckpt: bool = True):
+                 auto_ckpt: bool = True, chip: Optional[int] = None):
         self.name = name
         self.spec = {
             "name": name,
@@ -161,7 +234,11 @@ class ServerProcess:
             "smoke": smoke,
             "heartbeat_interval": heartbeat_interval,
             "auto_ckpt": auto_ckpt,
+            # TPU chip this server owns (None: JAX's default devices)
+            "chip": chip,
         }
+        #: the child's devices as JAX reports them, set once it is ready
+        self.device: Optional[dict] = None
         self._req_q = _CTX.Queue()
         self._resp_q = _CTX.Queue()
         self._proc: Optional[Any] = None
@@ -186,6 +263,7 @@ class ServerProcess:
         msg = self._next_resp(ready_timeout)
         if not msg.get("ready"):
             raise ServerProcessError(f"{self.name} failed to start: {msg}")
+        self.device = msg["device"]
         return self
 
     def restart(self, *, ready_timeout: float = 180.0) -> "ServerProcess":
@@ -198,8 +276,12 @@ class ServerProcess:
         self._req_q = _CTX.Queue()
         self._resp_q = _CTX.Queue()
         self.generation += 1
-        self.restarts += 1
-        return self.start(ready_timeout=ready_timeout)
+        try:
+            return self.start(ready_timeout=ready_timeout)
+        finally:
+            # counted once the respawn is ready (or has failed): a caller
+            # waiting on it must not race start() for the ready message
+            self.restarts += 1
 
     @property
     def pid(self) -> Optional[int]:
@@ -220,6 +302,16 @@ class ServerProcess:
         self.served += 1
         return msg
 
+    def reference_check(self, prompt, served, margin: float,
+                        timeout: Optional[float] = None) -> dict:
+        """``InferenceServer.reference_check`` run inside the child, on
+        its own device and weights. Queued behind the requests already
+        submitted, so collect their results first."""
+        self._rid += 1
+        self._req_q.put(("check", self._rid, list(prompt), list(served),
+                         float(margin)))
+        return self._next_resp(timeout)
+
     def _next_resp(self, timeout: Optional[float]) -> dict:
         deadline = None if timeout is None else time.monotonic() + timeout
         gen = self.generation
@@ -227,6 +319,10 @@ class ServerProcess:
         while True:
             step = 0.5 if deadline is None else max(
                 0.0, min(0.5, deadline - time.monotonic()))
+            # sampled before the wait, so a child that died after putting
+            # its last message (a fatal traceback) is read, not reported
+            # as a bare death
+            dead = not self.alive()
             try:
                 msg = resp_q.get(timeout=step)
             except queue_mod.Empty:
@@ -235,7 +331,7 @@ class ServerProcess:
                     # old response stream is dead, surface it
                     raise ServerProcessError(
                         f"server process {self.name} restarted mid-request")
-                if not self.alive():
+                if dead:
                     raise ServerProcessError(
                         f"server process {self.name} (pid={self.pid}) died")
                 if deadline is not None and time.monotonic() >= deadline:
@@ -288,6 +384,11 @@ class MultiProcessGateway:
                  restart_window: float = 30.0,
                  restart_backoff: tuple = (0.5, 8.0),
                  poll_interval: float = 0.2):
+        chips = tpu_chip_count()
+        if chips and len(archs) > chips:
+            raise ServerProcessError(
+                f"{len(archs)} server processes need one TPU chip each; "
+                f"this host has {chips}")
         self.broker: Optional[NodeBroker] = None
         broker_path = None
         if coordinate:
@@ -298,8 +399,9 @@ class MultiProcessGateway:
         self.servers = [
             ServerProcess(name, arch, broker_path=broker_path,
                           slots=slots_per_server, share=shares.get(name),
-                          max_batch=max_batch, max_len=max_len, smoke=smoke)
-            for name, arch in archs.items()
+                          max_batch=max_batch, max_len=max_len, smoke=smoke,
+                          chip=i if chips else None)
+            for i, (name, arch) in enumerate(archs.items())
         ]
         self.supervise = bool(supervise)
         self.max_restarts = int(max_restarts)

@@ -11,7 +11,13 @@ from hypothesis import given, settings, strategies as hst
 
 from repro.kernels import ops, ref
 
-jax.config.update("jax_default_matmul_precision", "highest")
+
+@pytest.fixture(autouse=True, scope="module")
+def _highest_matmul_precision():
+    """fp32-exact oracles for this file only: a config set at import would
+    leak into every test file a worker collects."""
+    with jax.default_matmul_precision("highest"):
+        yield
 
 
 def _tol(dtype):

@@ -157,7 +157,6 @@ def test_quantize_roundtrip():
 
 def test_compressed_psum_matches_fp32():
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.runtime.dist import compressed_psum
@@ -168,7 +167,7 @@ def test_compressed_psum_matches_fp32():
     x = jnp.asarray(
         np.random.default_rng(1).normal(size=(8, 128)), jnp.float32
     )
-    f = shard_map(lambda a: compressed_psum(a, "pod"), mesh=mesh,
+    f = jax.shard_map(lambda a: compressed_psum(a, "pod"), mesh=mesh,
                   in_specs=P("pod"), out_specs=P("pod"))
     got = np.asarray(f(x))
     want = np.asarray(x.sum(0, keepdims=True))

@@ -147,3 +147,35 @@ def test_serving_engine_under_usf():
         s2.stop()
     finally:
         usf.shutdown(timeout=5.0)
+
+
+def test_served_tokens_match_full_forward_reference():
+    """Greedy tokens served through the KV cache agree with ``LM.forward``
+    teacher-forced over prompt + answer, and a wrong token is caught."""
+    from repro.serve.engine import Gateway, InferenceServer
+
+    usf = UsfRuntime(Topology(2, 1), SchedCoop(quantum=0.05))
+    try:
+        srv = InferenceServer("srv", get_smoke("smollm_360m"), usf,
+                              max_batch=2, max_len=32)
+        srv.start()
+        gw = Gateway(usf, [srv])
+        prompt = [5, 6, 7, 8, 9]
+        out = []
+        t = usf.create(lambda: out.append(gw.handle(prompt, max_new=6)),
+                       job=gw.job)
+        assert usf.join(t, timeout=120.0)
+        served = out[0]["outputs"]["srv"]
+        assert len(served) == 6
+        chk = srv.reference_check(prompt, served, margin=0.0)
+        assert chk == {"positions": 6, "checked": 6, "mismatches": 0,
+                       "ok": True}
+        wrong = list(served)
+        wrong[2] = (wrong[2] + 1) % srv.cfg.vocab
+        bad = srv.reference_check(prompt, wrong, margin=0.0)
+        assert not bad["ok"] and bad["mismatches"] >= 1
+        with pytest.raises(ValueError):
+            srv.reference_check(prompt, served * 6, margin=0.0)  # > max_len
+        srv.stop()
+    finally:
+        usf.shutdown(timeout=5.0)
