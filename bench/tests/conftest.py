@@ -1,0 +1,12 @@
+import os
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import jax  # noqa: E402
+
+# CPU programs stay out of the persistent cache the chip runs use
+jax.config.update("jax_enable_compilation_cache", False)
