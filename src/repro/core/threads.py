@@ -32,7 +32,9 @@ verified in tests/test_threads.py. Worker reuse gives a *new* task a fresh
 
 from __future__ import annotations
 
+import contextlib
 import heapq
+import sys
 import threading
 import time
 from collections import deque
@@ -384,6 +386,19 @@ class _Watchdog:
                 fn()
 
 
+#: the profiler span around every park (``UsfRuntime._park``)
+PARK_SPAN = "usf.park"
+
+
+def _span(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` once this process has loaded
+    JAX, else a no-op. The scheduler never imports JAX itself: a process
+    that must stay off the chip (the multi-process gateway) runs it too,
+    and without JAX there is no profiler to write to."""
+    ann = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    return contextlib.nullcontext() if ann is None else ann(name)
+
+
 class _Worker:
     """A cached OS thread that serves one task at a time."""
 
@@ -731,8 +746,10 @@ class UsfRuntime:
             return w
 
     def _park(self, task: Task) -> None:
-        """Wait until the scheduler dispatches ``task`` to a slot again."""
-        task._resume_sem.acquire()  # type: ignore[attr-defined]
+        """Wait until the scheduler dispatches ``task`` to a slot again: the
+        thread is off its slot, blocked or ready, in a ``usf.park`` span."""
+        with _span(PARK_SPAN):
+            task._resume_sem.acquire()  # type: ignore[attr-defined]
 
     def _on_dispatch(self, task: Task, slot_id: int) -> None:
         if self._ticks_enabled:

@@ -1,11 +1,14 @@
 """Oversubscribed serving engine — the paper's §5.5 scenario, real JAX.
 
 Each ``InferenceServer`` is a USF *job* with worker tasks that run
-continuous-batching decode loops over a slot-based KV cache. Every wait —
-request-queue get, batch formation, device-step completion — is an
-intercepted USF blocking point, so SCHED_COOP multiplexes the servers
-(and the gateway) over slots at *application* boundaries, never preempting
-a decode burst mid-flight (the HBM-residency analogue of cache affinity).
+continuous-batching decode loops over a slot-based KV cache. The
+request-queue get of an idle server is an intercepted USF blocking point,
+and each step dispatch a preemption point, so SCHED_COOP multiplexes the
+servers (and the gateway) over slots at *application* boundaries, never
+preempting a decode burst mid-flight (the HBM-residency analogue of cache
+affinity). The wait for each step's completion is not intercepted: the
+worker keeps its slot while the device runs (the ``serve.device_wait``
+span and counter, ``repro.trace.serve_obs``).
 
 The gateway fans a request to several model servers and joins the
 responses (the paper's agentic benchmark: LLaMA + GPT-2 + RoBERTa).
@@ -29,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.autockpt import wrap_jit
 from repro.core.policies import Policy, SchedCoop
 from repro.core.scheduler import REC_REQ_DONE, REC_REQUEST
 from repro.core.sync import CoopChannel, CoopEvent
@@ -39,6 +41,7 @@ from repro.launch.inputs import make_decode_inputs
 from repro.models.base import init_tree
 from repro.models.registry import build_model
 from repro.runtime.sharding import Sharder
+from repro.trace import serve_obs as obs
 from repro.train.step import make_serve_step
 
 _RID = itertools.count()
@@ -60,8 +63,11 @@ class Request:
     tokens: list[int]
     max_new: int = 8
     rid: int = dataclasses.field(default_factory=lambda: next(_RID))
+    #: ``time.monotonic`` stamps: submitted (or due), given a cache row,
+    #: first output token about to be appended, retired
     arrival: float = 0.0
     started: float = 0.0
+    first_token: float = 0.0
     finished: float = 0.0
     #: absolute SLO deadline (``time.monotonic`` domain); None = best-effort
     deadline: Optional[float] = None
@@ -105,16 +111,26 @@ class InferenceServer:
                                 self.model.param_specs(), cfg.param_dtype)
         self._step = jax.jit(make_serve_step(self.model, self.sharder),
                              donate_argnums=(1,))
-        if auto_ckpt:
-            # every decode dispatch is a preemption point: a broker revoke
-            # or elastic shrink parks this worker within ~one engine step
-            # even when the batch never drains (docs/PREEMPTION.md tier 3)
-            self._step = wrap_jit(self._step, runtime=usf)
+        # every decode dispatch is a preemption point: a broker revoke or
+        # elastic shrink parks this worker within ~one engine step even
+        # when the batch never drains (docs/PREEMPTION.md tier 3)
+        self._auto_ckpt = auto_ckpt
         self._reference = jax.jit(
             functools.partial(_greedy_reference, self.model, self.sharder))
         self._task = None
         self._stop = False
-        self.served = 0
+        self._counters = obs.ServeCounters()
+        obs.count_compiles()
+
+    @property
+    def served(self) -> int:
+        """Requests retired so far."""
+        return self._counters.finished
+
+    def stats(self) -> dict:
+        """A snapshot of the decode loop's counters
+        (``repro.trace.serve_obs.ServeCounters``)."""
+        return self._counters.as_dict()
 
     # ------------------------------------------------------------------ #
     def submit(self, req: Request) -> Request:
@@ -215,6 +231,9 @@ class InferenceServer:
     def _serve_loop(self) -> None:
         cfg = self.cfg
         B = self.max_batch
+        c = self._counters
+        clock = time.monotonic
+        span = jax.profiler.TraceAnnotation
         cache, _, _ = make_decode_inputs(cfg, B, self.max_len,
                                          jax.random.PRNGKey(1))
         active: list[Optional[Request]] = [None] * B
@@ -224,51 +243,91 @@ class InferenceServer:
         cur = np.zeros(B, np.int64)
 
         while not self._stop:
-            # admit requests into free slots (continuous batching)
-            for i in range(B):
-                if active[i] is None:
-                    req = self.queue.try_get() if any(
-                        a is not None for a in active
-                    ) else self.queue.get()  # block only when fully idle
-                    if req is None:
-                        if self._stop:
-                            return
-                        continue
-                    req.started = time.monotonic()
-                    active[i] = req
-                    pos[i] = 0
-                    remaining[i] = req.max_new
-                    pending_tokens[i] = list(req.tokens)
-                    cur[i] = pending_tokens[i].pop(0)
+            # a fully idle server blocks off its slot (a ``usf.park``),
+            # outside every phase span
+            req = None
             if all(a is None for a in active):
-                continue
+                req = self.queue.get()
+                if req is None:
+                    continue  # the stop sentinel
+            # admit requests into free slots (continuous batching)
+            t0 = clock()
+            with span(obs.ADMIT) as sp:
+                admitted = []
+                for i in range(B):
+                    if active[i] is None:
+                        if req is None:
+                            req = self.queue.try_get()
+                        if req is None:
+                            if self._stop:
+                                return
+                            continue
+                        req.started = clock()
+                        active[i] = req
+                        pos[i] = 0
+                        remaining[i] = req.max_new
+                        pending_tokens[i] = list(req.tokens)
+                        cur[i] = pending_tokens[i].pop(0)
+                        admitted.append(req.rid)
+                        req = None
+                rows = B - active.count(None)
+                sp.set_metadata(rows=rows)
+                if admitted:
+                    sp.set_metadata(rid=" ".join(map(str, admitted)))
+            t1 = clock()
+            c.admit_s += t1 - t0
+            c.admitted += len(admitted)
 
             # one engine step: each active slot advances one token
-            toks = jnp.asarray(cur, jnp.int32)
-            p = jnp.asarray(pos, jnp.int32)
-            if cfg.mrope_sections is not None:
-                p = jnp.broadcast_to(p, (3, B))
-            logits, cache = self._step(self.params, cache, toks, p)
-            logits.block_until_ready()  # the device wait: a blocking point
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            if self._auto_ckpt:
+                self.usf.checkpoint()  # may park: outside the dispatch span
+            t2 = clock()
+            with span(obs.DISPATCH, step=c.steps):
+                toks = jnp.asarray(cur, jnp.int32)
+                p = jnp.asarray(pos, jnp.int32)
+                if cfg.mrope_sections is not None:
+                    p = jnp.broadcast_to(p, (3, B))
+                logits, cache = self._step(self.params, cache, toks, p)
+            t3 = clock()
+            with span(obs.DEVICE_WAIT):
+                # not intercepted by USF: the worker keeps its slot while
+                # the device runs
+                logits.block_until_ready()
+            t4 = clock()
+            with span(obs.FETCH):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            t5 = clock()
+            c.steps += 1
+            c.rows += rows
+            c.dispatch_s += t3 - t2
+            c.device_wait_s += t4 - t3
+            c.fetch_s += t5 - t4
 
-            for i in range(B):
-                req = active[i]
-                if req is None:
-                    continue
-                pos[i] += 1
-                if pending_tokens[i]:
-                    cur[i] = pending_tokens[i].pop(0)  # still prefilling
-                    continue
-                req.output.append(int(nxt[i]))
-                cur[i] = int(nxt[i])
-                remaining[i] -= 1
-                if remaining[i] <= 0 or pos[i] >= self.max_len - 1:
-                    req.finished = time.monotonic()
-                    self.served += 1
-                    self._retire(req)
-                    req.done.set()
-                    active[i] = None
+            with span(obs.BOOKKEEP) as sp:
+                done = 0
+                for i in range(B):
+                    req = active[i]
+                    if req is None:
+                        continue
+                    pos[i] += 1
+                    if pending_tokens[i]:
+                        cur[i] = pending_tokens[i].pop(0)  # still prefilling
+                        continue
+                    if remaining[i] == req.max_new:
+                        req.first_token = clock()
+                    req.output.append(int(nxt[i]))
+                    cur[i] = int(nxt[i])
+                    remaining[i] -= 1
+                    if remaining[i] <= 0 or pos[i] >= self.max_len - 1:
+                        req.finished = clock()
+                        c.finished += 1
+                        done += 1
+                        self._retire(req)
+                        req.done.set()
+                        active[i] = None
+                if done:
+                    sp.set_metadata(finished=done)
+            c.bookkeep_s += clock() - t5
 
 
 class Gateway:

@@ -26,10 +26,22 @@ losing records.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from typing import Optional
 
 from repro.trace import schema
+
+
+def profiler_clock_offset_ns() -> int:
+    """What to add to ``time.monotonic()``, in nanoseconds, to land on the
+    JAX profiler's clock: the realtime clock, in nanoseconds since the
+    epoch. A trace's events sit at its ``profile_start_time`` (a stat of
+    its ``Task Environment`` plane) plus their ``start_ns``."""
+    a = time.monotonic_ns()
+    now = time.time_ns()
+    b = time.monotonic_ns()
+    return now - (a + b) // 2
 
 
 class TraceRecorder:
@@ -41,14 +53,19 @@ class TraceRecorder:
     flush_at:   records per JSONL write batch in the background writer.
     poll_s:     background-writer drain interval (bounds ring occupancy
                 at roughly ``producer rate x poll_s`` records).
-    meta:       free-form dict stored in the trace header.
+    meta:       free-form dict stored in the trace header, beside
+                ``clock_offset_ns`` (``profiler_clock_offset_ns`` when the
+                recorder is made): a live runtime's record at ``t``
+                seconds lies at ``t * 1e9 + clock_offset_ns`` on the
+                profiler's clock.
     """
 
     def __init__(self, path: Optional[str] = None, *,
                  flush_at: int = 8192, poll_s: float = 0.05,
                  meta: Optional[dict] = None):
         self.path = path
-        self.meta = dict(meta or {})
+        self.meta = dict(meta or {},
+                         clock_offset_ns=profiler_clock_offset_ns())
         self._ring: deque = deque()
         self._flush_at = flush_at
         self._poll_s = poll_s

@@ -10,9 +10,10 @@ with a scan whose carry is the fp32 grad accumulator.
 Preemption: nothing in these factories checkpoints, deliberately — a
 ``usf.checkpoint()`` cannot run inside a traced function (it would
 execute once at trace time, then never again). The preemption point for
-a jitted step is its *call site*: the trainer and the serving engine
-wrap the jitted function with ``repro.core.autockpt`` so every dispatch
-boundary checkpoints (docs/PREEMPTION.md tier 3).
+a jitted step is its *call site*: the trainer wraps the jitted function
+with ``repro.core.autockpt`` and the serving engine checkpoints before
+each step dispatch, so every dispatch boundary checkpoints
+(docs/PREEMPTION.md tier 3).
 """
 
 from __future__ import annotations
@@ -132,11 +133,20 @@ def make_prefill_step(model, sharder) -> Callable[[dict, dict], jax.Array]:
     return prefill_step
 
 
+#: the serve step's name, pinned: its jitted module is ``jit_serve_step``
+#: and its operations sit under the ``serve_step`` scope, whatever the
+#: function is called, so that a trace finds the step by this name
+SERVE_STEP = "serve_step"
+
+
 def make_serve_step(model, sharder) -> Callable[..., tuple[jax.Array, dict]]:
     """One decode token against a KV cache."""
 
-    def serve_step(params: dict, cache: dict, tokens: jax.Array,
-                   positions: jax.Array) -> tuple[jax.Array, dict]:
-        return model.decode_step(params, cache, tokens, positions, sharder)
+    def step(params: dict, cache: dict, tokens: jax.Array,
+             positions: jax.Array) -> tuple[jax.Array, dict]:
+        with jax.named_scope(SERVE_STEP):
+            return model.decode_step(params, cache, tokens, positions,
+                                     sharder)
 
-    return serve_step
+    step.__name__ = step.__qualname__ = SERVE_STEP
+    return step

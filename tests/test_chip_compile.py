@@ -58,12 +58,16 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile(fn, *args, **jit_kw):
-    compiled = jax.jit(fn, **jit_kw).lower(*args).compile()
+def _fits(compiled) -> bool:
     ma = compiled.memory_analysis()
     resident = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                 + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
-    assert 0 < resident < HBM_BYTES
+    return 0 < resident < HBM_BYTES
+
+
+def _compile(fn, *args, **jit_kw):
+    compiled = jax.jit(fn, **jit_kw).lower(*args).compile()
+    assert _fits(compiled)
     return compiled
 
 
@@ -78,15 +82,27 @@ def smollm():
     return cfg, build_model(cfg)
 
 
-def test_serve_step_compiles(chip, smollm):
+@pytest.fixture(scope="module")
+def serve_step(chip, smollm):
     """The full-width decode step the smoke serves, cache donated."""
     cfg, model = smollm
     params = _on(abstract_tree(model.param_specs(), cfg.param_dtype), chip)
     cache = _on(abstract_tree(model.cache_specs(SMOKE_BATCH, SMOKE_LEN),
                               cfg.param_dtype), chip)
     tok = _spec((SMOKE_BATCH,), jnp.int32, chip)
-    _compile(make_serve_step(model, Sharder(None)), params, cache, tok, tok,
-             donate_argnums=(1,))
+    return jax.jit(make_serve_step(model, Sharder(None)),
+                   donate_argnums=(1,)).lower(params, cache, tok, tok).compile()
+
+
+def test_serve_step_compiles(serve_step):
+    assert _fits(serve_step)
+
+
+def test_serve_step_module_keeps_its_name(serve_step):
+    """Traces find the step by its module's name and its ops' scope."""
+    text = serve_step.as_text()
+    assert text.startswith("HloModule jit_serve_step")
+    assert 'op_name="jit(serve_step)/serve_step/' in text
 
 
 def test_reference_forward_compiles(chip, smollm):

@@ -294,7 +294,8 @@ def test_recorder_memory_vs_file_streams_identical(tmp_path):
 
     header, records = load_trace(path)
     assert header["kind"] == "decisions"
-    assert header["meta"] == {"who": "test"}
+    assert header["meta"] == {"who": "test",
+                              "clock_offset_ns": filed.meta["clock_offset_ns"]}
     # the sim is virtual-time deterministic, but tids/jids are process-
     # global — normalize both runs into a common (per-run-relative) space
     wl_mem, wl_file = reconstruct(mem.records()), reconstruct(records)
@@ -307,6 +308,36 @@ def test_disarmed_run_records_nothing():
     rec = TraceRecorder()
     _tiny_run(recorder=None)
     assert rec.records() == []
+
+
+def test_recorder_clock_offset_lands_on_the_profiler_clock(tmp_path):
+    """A ``time.monotonic`` stamp plus the header's ``clock_offset_ns``
+    falls where the CPU profiler put a span taken at that stamp."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    off = TraceRecorder().meta["clock_offset_ns"]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        before = time.monotonic_ns()
+        with jax.profiler.TraceAnnotation("offset.probe"):
+            time.sleep(0.01)
+        after = time.monotonic_ns()
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(
+        glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0])
+    start = next(v for pl in pd.planes if pl.name == "Task Environment"
+                 for k, v in pl.stats if k == "profile_start_time")
+    ev = next(e for pl in pd.planes for ln in pl.lines for e in ln.events
+              if e.name == "offset.probe")
+    slack = 500_000  # ns
+    assert before + off - slack <= start + ev.start_ns
+    assert start + ev.end_ns <= after + off + slack
+    assert ev.end_ns - ev.start_ns >= 10_000_000
 
 
 # --------------------------------------------------------------------- #
