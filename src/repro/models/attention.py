@@ -201,18 +201,23 @@ def cache_specs(cfg, batch: int, max_len: int, *, window: Optional[int]) -> dict
 
 
 def attention_decode(params: dict, cfg, sharder, x: jax.Array,
-                     cache: dict, positions: jax.Array, *,
+                     cache: dict, layer: jax.Array, positions: jax.Array, *,
                      window: Optional[int] = None) -> tuple[jax.Array, dict]:
     """x [B,1,d]; positions [B] absolute position of the new token (or
     [3,B] M-RoPE position streams for the VLM — the temporal stream [0]
     drives the cache slot and validity).
 
-    The cache slot is ``pos % W`` (ring buffer); for full attention W is
-    max_len so the ring is equivalent to a linear cache.
+    ``cache`` holds every layer of the stack ([L,B,W,KV,hd] keys and
+    values, [L,B,W] positions); this block's is index ``layer``. The new
+    key, value and position land in place at ``[layer, b, pos % W]``
+    (ring buffer; for full attention W is max_len so the ring is
+    equivalent to a linear cache). The write is a select over the layer
+    put back with a dynamic update, not a scatter: a scatter wants the
+    head dim minor, and XLA would relayout the cache around it.
     """
     dt = x.dtype
     B = x.shape[0]
-    W = cache["k"].shape[1]
+    W = cache["k"].shape[2]
     if positions.ndim == 2:  # [3, B] M-RoPE streams
         pos_t = positions[0]
         rope_pos = positions[:, :, None]  # [3,B,1]
@@ -229,12 +234,20 @@ def attention_decode(params: dict, cfg, sharder, x: jax.Array,
     q = apply_rope(q, rope_pos, cfg.rope_theta, cfg.mrope_sections)
     k = apply_rope(k, rope_pos, cfg.rope_theta, cfg.mrope_sections)
 
-    positions = pos_t
-    slots = (positions % W).astype(jnp.int32)  # [B]
-    bidx = jnp.arange(B)
-    k_cache = cache["k"].at[bidx, slots].set(k[:, 0].astype(cache["k"].dtype))
-    v_cache = cache["v"].at[bidx, slots].set(v[:, 0].astype(cache["v"].dtype))
-    pos_cache = cache["pos"].at[bidx, slots].set(positions.astype(jnp.int32))
+    positions = pos_t.astype(jnp.int32)
+    hit = jnp.arange(W) == (positions % W)[:, None]  # [B, W]
+
+    def put(buf, new):
+        """Layer ``layer`` of ``buf`` with ``new`` [B,1,...] at its slot,
+        and ``buf`` with that layer written back."""
+        old = jax.lax.dynamic_index_in_dim(buf, layer, keepdims=False)
+        sel = hit.reshape(hit.shape + (1,) * (old.ndim - 2))
+        cur = jnp.where(sel, new.astype(buf.dtype), old)
+        return cur, jax.lax.dynamic_update_index_in_dim(buf, cur, layer, 0)
+
+    k_cache, k_all = put(cache["k"], k)
+    v_cache, v_all = put(cache["v"], v)
+    pos_cache, pos_all = put(cache["pos"], positions[:, None])
 
     D = q.shape[-1]
     KV = k_cache.shape[2]
@@ -249,5 +262,4 @@ def attention_decode(params: dict, cfg, sharder, x: jax.Array,
     o = jnp.einsum("bkgw,bwkd->bkgd", p, v_cache.astype(jnp.float32))
     o = o.reshape(B, 1, q.shape[2], D).astype(dt)
     y = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(dt))
-    new_cache = {"k": k_cache, "v": v_cache, "pos": pos_cache}
-    return y, new_cache
+    return y, {"k": k_all, "v": v_all, "pos": pos_all}

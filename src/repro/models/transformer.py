@@ -346,34 +346,16 @@ class LM:
 
         fam = cfg.family
         if fam in ("dense", "vlm"):
-            def body(carry, xs):
-                p, c = xs
-                y, c2 = self._attn_decode_block(p, c, carry, positions)
-                return y, c2
-
-            x, new_layers = _maybe_scan(cfg, body, x, (params["layers"], cache["layers"]))
-            new_cache = {"layers": new_layers}
+            x, c = self._attn_decode_scan(params["layers"], cache["layers"],
+                                          x, positions)
+            new_cache = {"layers": c}
         elif fam == "moe":
             new_cache = {}
             if cfg.first_k_dense:
-                def body_d(carry, xs):
-                    p, c = xs
-                    y, c2 = self._attn_decode_block(p, c, carry, positions,
-                                                    dense=True)
-                    return y, c2
-
-                x, nd = _maybe_scan(
-                    cfg, body_d, x, (params["dense_layers"], cache["dense_layers"])
-                )
-                new_cache["dense_layers"] = nd
-
-            def body_m(carry, xs):
-                p, c = xs
-                y, c2 = self._moe_decode_block(p, c, carry, positions)
-                return y, c2
-
-            x, nl = _maybe_scan(cfg, body_m, x, (params["layers"], cache["layers"]))
-            new_cache["layers"] = nl
+                x, new_cache["dense_layers"] = self._attn_decode_scan(
+                    params["dense_layers"], cache["dense_layers"], x, positions)
+            x, new_cache["layers"] = self._attn_decode_scan(
+                params["layers"], cache["layers"], x, positions)
         elif fam == "ssm":
             def body_s(carry, xs):
                 p, c = xs
@@ -384,19 +366,24 @@ class LM:
             x, nl = _maybe_scan(cfg, body_s, x, (params["layers"], cache["layers"]))
             new_cache = {"layers": nl}
         elif fam == "hybrid":
+            # the recurrent states have no position axis: they stay the
+            # scan's xs/ys; the attention cache rides in the carry
             def body_h(carry, xs):
+                y, ca, i = carry
                 p, c = xs
-                y = carry
                 y, c1 = self._rec_decode_block(p["rec1"], c["rec1"], y)
                 y, c2 = self._rec_decode_block(p["rec2"], c["rec2"], y)
-                y, c3 = self._attn_decode_block(
-                    p["attn"], c["attn"], y, positions, window=cfg.local_window
+                y, ca = self._attn_decode_block(
+                    p["attn"], ca, i, y, positions, window=cfg.local_window
                 )
-                return y, {"rec1": c1, "rec2": c2, "attn": c3}
+                return (y, ca, i + 1), {"rec1": c1, "rec2": c2}
 
-            x, nsb = _maybe_scan(
-                cfg, body_h, x, (params["superblocks"], cache["superblocks"])
+            sb = cache["superblocks"]
+            (x, ca, _), nsb = _maybe_scan(
+                cfg, body_h, (x, sb["attn"], jnp.int32(0)),
+                (params["superblocks"], {"rec1": sb["rec1"], "rec2": sb["rec2"]}),
             )
+            nsb["attn"] = ca
             new_tail = {}
             for i in sorted(params["tail"], key=int):
                 x, ct = self._rec_decode_block(
@@ -411,15 +398,29 @@ class LM:
         return logits, new_cache
 
     # decode block helpers ------------------------------------------------- #
-    def _attn_decode_block(self, p, c, x, positions, *, window=None, dense=None):
+    def _attn_decode_scan(self, params, cache, x, positions):
+        """Decode through a stack of attention blocks. The stacked cache
+        and the layer index ride in the scan's carry, so each block
+        writes its slot in place and the cache stays one buffer in one
+        layout; only the parameters are scanned over."""
+        def body(carry, p):
+            y, c, i = carry
+            y, c = self._attn_decode_block(p, c, i, y, positions)
+            return (y, c, i + 1), None
+
+        (x, cache, _), _ = _maybe_scan(
+            self.cfg, body, (x, cache, jnp.int32(0)), params)
+        return x, cache
+
+    def _attn_decode_block(self, p, c, layer, x, positions, *, window=None):
         cfg = self.cfg
         win = window if window is not None else cfg.swa_window
         pos_b = positions if positions.ndim == 1 else positions[0]
         sharder = self._sharder
         h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
         h, c2 = attn.attention_decode(
-            p["attn"], cfg, sharder, h,
-            c, positions if cfg.mrope_sections else pos_b, window=win,
+            p["attn"], cfg, sharder, h, c, layer,
+            positions if cfg.mrope_sections else pos_b, window=win,
         )
         x = x + h
         h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
@@ -434,9 +435,6 @@ class LM:
             hh, _ = moe_mod.moe_block(p["moe"], cfg, sharder, hh)
             h = jnp.swapaxes(hh, 0, 1)
         return x + h, c2
-
-    def _moe_decode_block(self, p, c, x, positions):
-        return self._attn_decode_block(p, c, x, positions)
 
     def _rec_decode_block(self, p, c, x):
         cfg = self.cfg

@@ -10,6 +10,10 @@ such compiles live in this one file: a second file could land on another
 worker, whose fixture would skip in silence.
 """
 
+import dataclasses
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -25,6 +29,15 @@ from repro.train.step import make_serve_step
 HBM_BYTES = 16 * 1024**3  # one v5e chip
 # chip_smoke.py's batch and cache length (MAX_BATCH, MAX_LEN)
 SMOKE_BATCH, SMOKE_LEN = 8, 128
+# the benchmark's serving shape (``max_batch``, ``max_len`` in
+# bench/configs/), and each configuration's stored weight dtype there
+BENCH_BATCH, BENCH_LEN = 32, 768
+BENCH_ARCHS = {"smollm_360m": "float32", "h2o_danube_3_4b": "bfloat16"}
+# the step's temporaries at that shape when it wrote the cache by a scatter
+# into the scan's per-layer slice and returned the cache as the scan's
+# output: relayouts around the scatter, and a second stacked cache
+SCATTER_TEMP_BYTES = {"smollm_360m": 1_649_926_144,
+                      "h2o_danube_3_4b": 2_362_139_648}
 
 
 @pytest.fixture(scope="module")
@@ -103,6 +116,57 @@ def test_serve_step_module_keeps_its_name(serve_step):
     text = serve_step.as_text()
     assert text.startswith("HloModule jit_serve_step")
     assert 'op_name="jit(serve_step)/serve_step/' in text
+
+
+@pytest.fixture(scope="module", params=sorted(BENCH_ARCHS))
+def bench_step(request, chip):
+    """The donated decode step at the benchmark's shape: (arch, abstract
+    cache, compiled step)."""
+    arch = request.param
+    cfg = dataclasses.replace(get_arch(arch), param_dtype=BENCH_ARCHS[arch])
+    model = build_model(cfg)
+    params = _on(abstract_tree(model.param_specs(), cfg.param_dtype), chip)
+    cache = _on(abstract_tree(model.cache_specs(BENCH_BATCH, BENCH_LEN),
+                              cfg.param_dtype), chip)
+    tok = _spec((BENCH_BATCH,), jnp.int32, chip)
+    step = _compile(make_serve_step(model, Sharder(None)), params, cache,
+                    tok, tok, donate_argnums=(1,))
+    return arch, cache, step
+
+
+def _squeezed(shape) -> tuple:
+    return tuple(d for d in shape if d != 1)
+
+
+def test_bench_step_copies_no_cache(bench_step):
+    """No copy of the stacked cache or of one layer of it: the cache stays
+    in one buffer and one layout through the layer scan."""
+    _, cache, step = bench_step
+    cache_shapes = set()
+    for leaf in jax.tree_util.tree_leaves(cache):
+        cache_shapes |= {_squeezed(leaf.shape), _squeezed(leaf.shape[1:])}
+    copies = []
+    for m in re.finditer(r"%(\S+) = \w+\[([\d,]*)\]\S* copy\(",
+                         step.as_text()):
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if _squeezed(dims) in cache_shapes:
+            copies.append(m.group(0))
+    assert not copies
+
+
+def test_bench_step_aliases_cache(bench_step):
+    """The donated cache is updated in place: every byte of it aliases an
+    output."""
+    _, cache, step = bench_step
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    assert step.memory_analysis().alias_size_in_bytes == cache_bytes
+
+
+def test_bench_step_temporaries_halved(bench_step):
+    arch, _, step = bench_step
+    temp = step.memory_analysis().temp_size_in_bytes
+    assert temp < SCATTER_TEMP_BYTES[arch] / 2
 
 
 def test_reference_forward_compiles(chip, smollm):
