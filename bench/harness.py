@@ -3,9 +3,12 @@
 Everything a cell is made of is found by name: its configuration in
 ``bench/configs/<config>.json``, its traffic mix in
 ``bench/traffic/<traffic>.json``, each metric's reader in
-``bench/metrics/<metric>.py`` and the configuration's plain reference in
-``bench/reference/<reference>.py``. Adding a cell adds such files and
-``BENCHMARK.json`` entries; nothing here names a cell.
+``bench/metrics/<metric>.py`` and the configuration's family module in
+``bench/reference/<reference>.py``, which alone knows its architecture:
+its served weights, its step's work count and its plain reference
+(:func:`family`). Adding a cell, or a configuration of another
+architecture, adds such files and ``BENCHMARK.json`` entries; nothing here
+names a cell or a family.
 
 The window drives ``InferenceServer.submit`` from one client thread
 outside the scheduler. Each request carries an output list that stamps
@@ -62,6 +65,23 @@ def load_module(kind: str, name: str):
                                                   path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
+
+
+#: what the harness takes from a configuration's family module
+FAMILY_EXPORTS = ("served", "serve_step", "gaps")
+
+
+def family(conf: dict):
+    """``bench/reference/<conf["reference"]>.py``: ``served(conf, seed,
+    server, dtype)``, the served weights; ``serve_step(conf)``, the least
+    work of one serve-step call (``bench/work.py``); ``gaps(conf, seed,
+    server, seqs, control=...)``, the plain reference."""
+    mod = load_module("reference", conf["reference"])
+    missing = [n for n in FAMILY_EXPORTS if not hasattr(mod, n)]
+    if missing:
+        raise AttributeError(f"bench/reference/{conf['reference']}.py "
+                             f"lacks {', '.join(missing)}")
     return mod
 
 
@@ -205,13 +225,28 @@ class Deployment:
             self.cojob.start()
 
     def counters(self) -> dict:
+        """The counters ``drive`` takes window deltas of: the decode
+        workers' scheduler wait and dispatches, the co-job's finished
+        products, every numeric counter of ``InferenceServer.stats()``
+        summed over the servers, and the process's JAX traces and compiles
+        (``compiles``)."""
+        from repro.trace import serve_obs
+
         c = {"wait_s": 0.0, "dispatches": 0}
+        prog = {"compiles": serve_obs.compiles()}
         for s in self.servers:
             st = s._task.stats
             c["wait_s"] += st.wait_time
             c["dispatches"] += st.dispatches
+            for k, v in s.stats().items():
+                if isinstance(v, (int, float)) and not isinstance(v, bool):
+                    prog[k] = prog.get(k, 0) + v
         c["cojob_done"] = self.cojob.count() if self.cojob else 0
-        return c
+        clash = c.keys() & prog.keys()
+        if clash:
+            raise RuntimeError(f"program counters {sorted(clash)} clash "
+                               f"with the harness's own")
+        return {**c, **prog}
 
     def stop_cojob(self) -> None:
         if self.cojob is not None:
@@ -237,12 +272,10 @@ def load_weights(server, conf: dict, seed: int, index: int) -> None:
     after checking that they fill the same tree, shapes and dtypes."""
     import jax
 
-    from bench import weights as W
-
     want = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), server.params)
     server.params = None
     gc.collect()
-    params = W.served(conf, seed, index, conf["param_dtype"])
+    params = family(conf).served(conf, seed, index, conf["param_dtype"])
     got = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), params)
     if got != want:
         raise RuntimeError(f"benchmark weights do not fit the server's "
@@ -377,7 +410,7 @@ def check(conf: dict, seed: int, sent: list, sample_tokens: int, *,
     window and not finished when the drain ended. With ``control``, also
     the same gap for the tokens the float8 control puts first
     (``control_logit_gap``)."""
-    ref = load_module("reference", conf["reference"])
+    ref = family(conf)
     rng = np.random.default_rng(seed + 7)
     sample = choose_sample(sent, rng, sample_tokens)
     out = {"requests": len(sample),
@@ -430,6 +463,25 @@ def client_record(drv: dict, seconds: float) -> dict:
             "tokens_in_window": tokens, "late_s": late}
 
 
+def server_record(drv: dict) -> dict:
+    """The servers' stamps of the requests due in the window, for the
+    metric readers: ``queue_wait_s``, from due to a cache row (``started -
+    arrival``), and ``prefill_s``, from there to the first token
+    (``first_token - started``). A stamp not made by the end of the drain
+    counts as made then."""
+    t0, t1, end = drv["t0"], drv["t1"], drv["drained"]
+    wait, prefill = [], []
+    for s in drv["sent"]:
+        if not t0 <= s.sent < t1:
+            continue
+        r = s.req
+        started = r.started if 0 < r.started <= end else end
+        first = r.first_token if 0 < r.first_token <= end else end
+        wait.append(started - r.arrival)
+        prefill.append(first - started)
+    return {"queue_wait_s": wait, "prefill_s": prefill}
+
+
 def metric_names(bench: dict, cell: str, trace: bool) -> list[dict]:
     """The cell's end-to-end metrics, or with ``trace`` its per-layer
     ones: each metric that lists the cell, or lists no cells."""
@@ -446,19 +498,21 @@ def limits_check(conf: dict, chk: dict) -> dict:
 def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
              t_start: float, bench: Optional[dict] = None,
              conf: Optional[dict] = None, mix: Optional[dict] = None,
-             fault=None) -> tuple[dict, dict]:
-    """One run. Returns (result line, check). ``conf``/``mix`` replace the
-    cell's files (smaller sizes in tests); ``fault`` is called with the
-    deployment before the window, to break the timed path in tests."""
+             fault=None) -> tuple[dict, dict, dict]:
+    """One run. Returns (result line, check, run record: what the metric
+    readers read). ``conf``/``mix`` replace the cell's files (smaller
+    sizes in tests); ``fault`` is called with the deployment before the
+    window, to break the timed path in tests."""
     import jax
 
-    from bench import peaks, work
+    from bench import peaks
 
     bench = bench or load_benchmark()
     _, conf0, mix0 = cell_spec(bench, cell)
     conf, mix = conf or conf0, mix or mix0
     enable_cache()
     arch = arch_config(conf)
+    fam = family(conf)
     dev = jax.devices()[0]
     reqs = T.plan(mix, seed=seed, seconds=seconds, vocab=conf["vocab"],
                   n_servers=mix["deployment"]["servers"])
@@ -473,12 +527,14 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
         drv = drive(dep, mix, reqs, seconds, tracer)
         dep.stop_cojob()
         mem = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
-        cojob = dep.cojob
+        cojob, servers = dep.cojob, len(dep.servers)
         dep.shutdown()
         rec = {"client": client_record(drv, seconds),
-               "counters": drv["counters"], "setup_s": setup_s,
+               "server": server_record(drv),
+               "counters": drv["counters"], "servers": servers,
+               "max_batch": conf["max_batch"], "setup_s": setup_s,
                "cojob_flop": cojob.flop if cojob else None,
-               "work": work.serve_step(conf), "trace": None}
+               "work": fam.serve_step(conf), "trace": None}
         if trace:
             from bench import trace_reduce
 
@@ -512,4 +568,4 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool, *,
                      "sample_requests": chk["requests"],
                      "sample_tokens": chk["tokens"]}
     out["check"] = checks
-    return out, chk
+    return out, chk, rec

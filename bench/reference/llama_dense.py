@@ -1,8 +1,21 @@
-"""Plain reference of a dense decoder with grouped-query attention.
+"""The dense decoder family with grouped-query attention: everything the
+benchmark knows of it, for ``smollm_360m`` and ``h2o_danube3_4b``.
 
-The forward pass of ``smollm_360m`` and ``h2o_danube3_4b`` as their model
-cards describe it, in float32 at ``Precision.HIGHEST``, over whole
-sequences, with no cache and no batching tricks:
+A family's module exports what the harness takes by the configuration's
+``reference`` key:
+
+- ``served(conf, seed, server, dtype)``: one server's weights from the
+  seed, in the served tree (``TREE``, the seed discipline of
+  ``bench.weights``);
+- ``serve_step(conf)``: the least work of one serve-step call
+  (``bench.work``);
+- ``gaps(conf, seed, server, seqs, control=...)``: the plain reference;
+- ``small(conf, full_width=...)``: the configuration cut to a CPU's size,
+  for the rehearsal (``bench/tests``).
+
+The reference is the forward pass as the model cards describe it, in
+float32 at ``Precision.HIGHEST``, over whole sequences, with no cache and
+no batching tricks:
 
     x = E[tokens]
     per layer:  h = rmsnorm(x) * g1
@@ -15,8 +28,8 @@ sequences, with no cache and no batching tricks:
     logits = (rmsnorm(x) * g) U
 
 It imports nothing of the system under test and makes its own weights
-from the seed (``bench.weights``), one layer at a time, so that it fits
-beside nothing else on the chip.
+from the seed, one layer at a time, so that it fits beside nothing else
+on the chip.
 
 ``control=True`` runs a second stream beside it: the same model with every
 linear layer computed in float8 (e4m3, per-tensor weight and per-row
@@ -34,6 +47,88 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
+from bench import work
+
+
+def _shapes(c: dict) -> dict:
+    d, H, KV, hd, ff, V = (c["d_model"], c["n_heads"], c["n_kv_heads"],
+                           c["head_dim"], c["d_ff"], c["vocab"])
+    return {
+        "ln1": ((d,), None), "ln2": ((d,), None),
+        "wq": ((d, H, hd), d), "wk": ((d, KV, hd), d),
+        "wv": ((d, KV, hd), d), "wo": ((H, hd, d), H * hd),
+        "gate": ((d, ff), d), "up": ((d, ff), d), "down": ((ff, d), ff),
+        "embed": ((V, d), "embed"), "final_norm": ((d,), None),
+        "unembed": ((d, V), d),
+    }
+
+
+TREE = W.Tree(
+    layer_leaves=("ln1", "ln2", "wq", "wk", "wv", "wo", "gate", "up",
+                  "down"),
+    top_leaves=("embed", "final_norm", "unembed"),
+    paths={
+        "ln1": ("layers", "ln1"), "ln2": ("layers", "ln2"),
+        "wq": ("layers", "attn", "wq"), "wk": ("layers", "attn", "wk"),
+        "wv": ("layers", "attn", "wv"), "wo": ("layers", "attn", "wo"),
+        "gate": ("layers", "mlp", "gate"), "up": ("layers", "mlp", "up"),
+        "down": ("layers", "mlp", "down"),
+        "embed": ("embed", "tok"), "final_norm": ("final_norm",),
+        "unembed": ("unembed",),
+    },
+    shapes=_shapes,
+)
+
+
+served = TREE.served
+
+
+def small(c: dict, *, full_width: bool = False) -> dict:
+    """Two layers and, unless ``full_width``, tiny widths."""
+    c = dict(c, n_layers=2)
+    if not full_width:
+        c.update(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
+                 vocab=512)
+    return c
+
+
+def params(c: dict) -> dict:
+    """Weight counts by part."""
+    d, H, KV, hd, ff, V, L = (c["d_model"], c["n_heads"], c["n_kv_heads"],
+                              c["head_dim"], c["d_ff"], c["vocab"],
+                              c["n_layers"])
+    per_layer = (2 * d            # two norm gains
+                 + d * H * hd * 2  # q and o projections
+                 + d * KV * hd * 2  # k and v projections
+                 + 3 * d * ff)     # gate, up, down
+    return {"embed": V * d, "unembed": d * V, "final_norm": d,
+            "layers": L * per_layer,
+            "total": 2 * V * d + d + L * per_layer}
+
+
+def serve_step(c: dict) -> dict:
+    """One call decodes a token for every row of its ``max_batch x
+    max_len`` cache, in use or not: it reads every weight except the
+    embedding table, of which it gathers one row per batch row, and the
+    whole key and value cache with its position table; it does ``2 x
+    max_batch`` operations per matrix weight, and attention's ``q.k`` and
+    ``p.v`` over every cache position (the sliding window, where shorter)
+    for every head of every layer."""
+    p = params(c)
+    B, T, L = c["max_batch"], c["max_len"], c["n_layers"]
+    H, KV, hd, d = c["n_heads"], c["n_kv_heads"], c["head_dim"], c["d_model"]
+    win = min(c.get("swa_window") or T, T)
+    wb = work.BYTES[c["param_dtype"]]
+    cb = work.BYTES[c["compute_dtype"]]  # the cache is kept in compute dtype
+    matmul_weights = p["total"] - p["embed"]
+    weight_bytes = matmul_weights * wb + B * d * wb
+    cache_bytes = L * (2 * B * win * KV * hd * cb + B * win * 4)
+    attn_flops = L * B * 2 * (2 * H * hd * win)
+    return {"flops": 2.0 * B * matmul_weights + attn_flops,
+            "bytes": float(weight_bytes + cache_bytes),
+            "weight_bytes": float(weight_bytes),
+            "cache_bytes": float(cache_bytes)}
+
 
 HI = jax.lax.Precision.HIGHEST
 _F8 = jnp.float8_e4m3fn
@@ -96,14 +191,14 @@ def _block(c: dict, x, w: dict, fp8: bool):
 def _layer(cfg_items, base, l, xs, dtype):
     c = dict(cfg_items)
     w = {n: a.astype(jnp.float32)
-         for n, a in W.layer(c, base, l, dtype).items()}
+         for n, a in TREE.layer(c, base, l, dtype).items()}
     return tuple(_block(c, x, w, fp8) for x, fp8 in zip(xs, (False, True)))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
 def _embed(cfg_items, base, tokens, dtype):
     c = dict(cfg_items)
-    return W.top(c, base, dtype)["embed"].astype(jnp.float32)[tokens]
+    return TREE.top(c, base, dtype)["embed"].astype(jnp.float32)[tokens]
 
 
 @functools.partial(jax.jit, static_argnums=(0, 5, 6))
@@ -112,7 +207,7 @@ def _head(cfg_items, base, x_ref, x_ctl, nxt, dtype, control):
     logit of the next token, and, with the control, minus its logit of
     the token the control puts first."""
     c = dict(cfg_items)
-    top = W.top(c, base, dtype)
+    top = TREE.top(c, base, dtype)
     g = top["final_norm"].astype(jnp.float32)
     u = top["unembed"].astype(jnp.float32)
     lg = jnp.einsum("td,dv->tv", _rms(x_ref, g, c["norm_eps"]), u,

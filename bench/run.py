@@ -46,8 +46,9 @@ def main() -> int:
     w, _, _ = harness.cell_spec(bench, args.workload)
     if not entry.tpus(w["chips"]):
         return 2
-    out, _ = harness.run_cell(args.workload, args.seed, args.seconds,
-                              bool(args.trace), t_start=T_START, bench=bench)
+    out, _, _ = harness.run_cell(args.workload, args.seed, args.seconds,
+                                 bool(args.trace), t_start=T_START,
+                                 bench=bench)
     for k, c in out["check"].items():
         print(f"check {k} {c['value']!r} limit {c['limit']!r}",
               file=sys.stderr)

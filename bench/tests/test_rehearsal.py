@@ -22,17 +22,17 @@ CELLS = [w["name"] for w in BENCHMARK["workloads"]]
 
 def small(cell, *, full_width=False):
     """The cell's files cut to a CPU's size: two layers (and, unless
-    ``full_width``, tiny widths), 4 rows of 128 positions, short
-    requests, a slow arrival rate."""
+    ``full_width``, tiny widths) as the family's module cuts them, 4 rows
+    of 128 positions, short requests, a slow arrival rate."""
     _, conf, mix = harness.cell_spec(BENCHMARK, cell)
     return shrink(conf, mix, full_width=full_width)
 
 
 def shrink(conf, mix, *, full_width=False):
-    conf = dict(conf, n_layers=2, max_batch=4, max_len=128)
-    if not full_width:
-        conf.update(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
-                    d_ff=128, vocab=512)
+    """Sizes from the configuration's family module (``small``), 4 rows of
+    128 positions, and the mix cut to fit."""
+    conf = dict(harness.family(conf).small(conf, full_width=full_width),
+                max_batch=4, max_len=128)
     mix = copy.deepcopy(mix)
     mix["prompt_len"].update(median=12, min=4, max=64)
     mix["output_len"].update(median=8, min=4, max=60)
@@ -49,9 +49,9 @@ def shrink(conf, mix, *, full_width=False):
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_runs_end_to_end(cell):
     conf, mix = small(cell)
-    out, chk = harness.run_cell(cell, 2**31 + 17, 3.0, False,
-                                t_start=time.monotonic(), bench=BENCHMARK,
-                                conf=conf, mix=mix)
+    out, chk, _ = harness.run_cell(cell, 2**31 + 17, 3.0, False,
+                                   t_start=time.monotonic(), bench=BENCHMARK,
+                                   conf=conf, mix=mix)
     assert out["correct"], out["check"]
     assert out["attempted"] > 0 and out["failed"] == 0
     names = {m["name"] for m in harness.metric_names(BENCHMARK, cell, False)}
@@ -126,18 +126,18 @@ def _stale_cache(dep):
 @pytest.mark.parametrize("cell", CELLS)
 def test_altered_token_is_not_correct(cell):
     conf, mix = small(cell, full_width=True)
-    out, _ = harness.run_cell(cell, 11, 3.0, False,
-                              t_start=time.monotonic(), bench=BENCHMARK,
-                              conf=conf, mix=mix, fault=_alter_tokens)
+    out, _, _ = harness.run_cell(cell, 11, 3.0, False,
+                                 t_start=time.monotonic(), bench=BENCHMARK,
+                                 conf=conf, mix=mix, fault=_alter_tokens)
     assert out["correct"] is False, out["check"]
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_stale_cache_is_not_correct(cell):
     conf, mix = small(cell, full_width=True)
-    out, _ = harness.run_cell(cell, 13, 3.0, False,
-                              t_start=time.monotonic(), bench=BENCHMARK,
-                              conf=conf, mix=mix, fault=_stale_cache)
+    out, _, _ = harness.run_cell(cell, 13, 3.0, False,
+                                 t_start=time.monotonic(), bench=BENCHMARK,
+                                 conf=conf, mix=mix, fault=_stale_cache)
     assert out["correct"] is False, out["check"]
 
 
@@ -151,9 +151,9 @@ def _one_server_stops(dep):
 def test_unanswered_requests_are_not_correct(cell, monkeypatch):
     monkeypatch.setattr(harness, "DRAIN_CAP_S", 2.0)
     conf, mix = small(cell)
-    out, chk = harness.run_cell(cell, 17, 3.0, False,
-                                t_start=time.monotonic(), bench=BENCHMARK,
-                                conf=conf, mix=mix, fault=_one_server_stops)
+    out, chk, _ = harness.run_cell(cell, 17, 3.0, False,
+                                   t_start=time.monotonic(), bench=BENCHMARK,
+                                   conf=conf, mix=mix, fault=_one_server_stops)
     assert chk["unanswered"] > 0 and out["failed"] > 0
     assert out["correct"] is False, out["check"]
 

@@ -9,8 +9,7 @@ import json
 import pytest
 
 from bench import trace_reduce as R
-from bench import work
-from bench.harness import BENCH, load_module
+from bench.harness import BENCH, family, load_module
 from bench.peaks import peaks
 
 SMALL = BENCH / "data" / "small.xplane.pb.gz"
@@ -40,7 +39,7 @@ def test_pinned_numbers(reduced):
 
 def test_trace_metrics_read_the_trace(reduced):
     conf = json.loads((BENCH / "configs" / "smollm_360m.json").read_text())
-    rec = {"trace": reduced, "work": work.serve_step(conf),
+    rec = {"trace": reduced, "work": family(conf).serve_step(conf),
            "peak": peaks("TPU v5 lite")}
     got = {m: load_module("metrics", m).value(rec)
            for m in ("host_gap_ms", "serve_step_roofline", "step_mfu",

@@ -4,8 +4,10 @@ import json
 
 import pytest
 
-from bench import peaks, work
-from bench.harness import BENCH
+from bench import peaks
+from bench.harness import BENCH, load_module
+
+work = load_module("reference", "llama_dense")
 
 
 def conf(name):
