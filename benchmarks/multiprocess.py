@@ -513,7 +513,7 @@ def _real_colocate_worker(mode: str, broker_path, slots: int, phases: int,
                     cfg, 1, max_len, jax.random.PRNGKey(i))
                 last = time.monotonic()
                 for _ in range(phases):
-                    logits, cache = wstep(params, cache, tok, p)
+                    logits, cache, _ = wstep(params, cache, tok, p)
                     logits.block_until_ready()
                     tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     p = (p + 1) % (max_len - 1)

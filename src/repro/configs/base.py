@@ -25,6 +25,7 @@ _ARCH_IDS = [
     "recurrentgemma_9b",
     "qwen2_vl_7b",
     "hubert_xlarge",
+    "deepseek_v2_lite",
 ]
 
 # public ids use dashes (CLI-friendly); module names use underscores
@@ -52,6 +53,18 @@ class ArchConfig:
     frontend: str = "token"           # token | patch | frame (stubs for vlm/audio)
     frontend_dim: int = 0             # embedding dim provided by the stub
     norm_eps: float = 1e-6
+    # --- latent attention (MLA, deepseek-v2); kv_lora_rank 0: none ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # --- YaRN rope scaling (deepseek-v2); rope_scaling_factor 0: none ---
+    rope_scaling_factor: float = 0.0
+    rope_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # --- MoE ---
     n_experts: int = 0
     n_shared_experts: int = 0
@@ -59,6 +72,12 @@ class ArchConfig:
     expert_d_ff: int = 0
     first_k_dense: int = 0
     capacity_factor: float = 1.25
+    #: renormalise the top-k gates to sum to 1 (deepseek-v2: no)
+    norm_topk_prob: bool = True
+    #: chips each layer's routed experts are split over (expert
+    #: parallelism); this chip holds ``experts_held`` of them, ids 0 ..
+    #: experts_held-1, and the router keeps all n_experts outputs
+    expert_parallel: int = 1
     moe_aux_loss: float = 0.01
     # --- SSM (mamba2 / SSD) ---
     ssm_state: int = 0
@@ -94,6 +113,11 @@ class ArchConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts this chip holds: its share of ``n_experts``."""
+        return self.n_experts // self.expert_parallel
 
     @property
     def sub_quadratic(self) -> bool:

@@ -180,7 +180,7 @@ def build_cell(cfg, shape, mesh, *, microbatches: int = 1,
             fn,
             (params_abs, cache_abs, tok_abs, pos_abs),
             (params_sh, cache_sh, tok_sh, pos_sh),
-            (None, cache_sh),
+            (None, cache_sh, None),
         )
 
     raise ValueError(shape.kind)

@@ -1,4 +1,5 @@
-"""Attention: GQA with full / sliding-window / local / bidirectional masks.
+"""Attention: GQA with full / sliding-window / local / bidirectional masks,
+and multi-head latent attention (MLA, deepseek-v2: ``cfg.kv_lora_rank``).
 
 Backends:
   * ``reference`` — materializes the score matrix (small smoke tests, and
@@ -25,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.base import ParamSpec
-from repro.models.layers import apply_rope
+from repro.models.layers import apply_rope, rmsnorm, yarn_freqs, yarn_mscale
 
 _NEG = -1.0e30
 
@@ -34,6 +35,8 @@ _NEG = -1.0e30
 # parameter specs
 # --------------------------------------------------------------------------- #
 def attn_specs(cfg) -> dict:
+    if cfg.kv_lora_rank:
+        return mla_specs(cfg)
     d, H, KV, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     # "fan_in" divides by shape[-2], but the fan-in of [d, H, hd] is d and
     # that of [H, hd, d] is H*hd: rescale to 1/sqrt(true fan-in). Without
@@ -77,24 +80,24 @@ def _mask(q_pos: jax.Array, kv_pos: jax.Array, mode: str,
 # --------------------------------------------------------------------------- #
 # full-sequence attention (train / prefill)
 # --------------------------------------------------------------------------- #
-def _reference_attention(q, k, v, mode, window):
+def _reference_attention(q, k, v, mode, window, scale):
     B, S, H, D = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    T, KV, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
-    qr = (q * (D ** -0.5)).reshape(B, S, KV, G, D)
+    qr = (q * scale).reshape(B, S, KV, G, D)
     s = jnp.einsum("bskgd,btkd->bkgst", qr.astype(jnp.float32),
                    k.astype(jnp.float32))
     m = _mask(jnp.arange(S), jnp.arange(T), mode, window)
     s = jnp.where(m[None, None, None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgst,btkd->bskgd", p, v.astype(jnp.float32))
-    return o.reshape(B, S, H, D).astype(q.dtype)
+    return o.reshape(B, S, H, Dv).astype(q.dtype)
 
 
-def _chunked_attention(q, k, v, mode, window, chunk):
+def _chunked_attention(q, k, v, mode, window, chunk, scale):
     """Streaming-softmax (flash) attention via lax.scan over KV chunks."""
     B, S, H, D = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    T, KV, Dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
     chunk = min(chunk, T)
     if T % chunk != 0:  # pad KV to a chunk multiple; padded keys are masked
@@ -103,9 +106,9 @@ def _chunked_attention(q, k, v, mode, window, chunk):
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     Tp = k.shape[1]
     nc = Tp // chunk
-    qr = (q.astype(jnp.float32) * (D ** -0.5)).reshape(B, S, KV, G, D)
+    qr = (q.astype(jnp.float32) * scale).reshape(B, S, KV, G, D)
     kc = jnp.moveaxis(k.reshape(B, nc, chunk, KV, D), 1, 0)  # [nc,B,c,KV,D]
-    vc = jnp.moveaxis(v.reshape(B, nc, chunk, KV, D), 1, 0)
+    vc = jnp.moveaxis(v.reshape(B, nc, chunk, KV, Dv), 1, 0)
     q_pos = jnp.arange(S)
 
     def body(carry, xs):
@@ -127,23 +130,29 @@ def _chunked_attention(q, k, v, mode, window, chunk):
 
     m0 = jnp.full((B, KV, G, S), _NEG, jnp.float32)
     l0 = jnp.zeros((B, KV, G, S), jnp.float32)
-    a0 = jnp.zeros((B, KV, G, S, D), jnp.float32)
+    a0 = jnp.zeros((B, KV, G, S, Dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0),
                                   (kc, vc, jnp.arange(nc)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    out = jnp.moveaxis(out, [1, 2], [2, 3]).reshape(B, S, H, D)
+    out = jnp.moveaxis(out, [1, 2], [2, 3]).reshape(B, S, H, Dv)
     return out.astype(q.dtype)
 
 
 def multihead_attention(q, k, v, *, mode: str = "causal",
                         window: Optional[int] = None,
-                        backend: str = "chunked", chunk: int = 1024):
-    """q [B,S,H,D]; k,v [B,T,KV,D] with H % KV == 0 (GQA)."""
+                        backend: str = "chunked", chunk: int = 1024,
+                        scale: Optional[float] = None):
+    """q, k [B,S|T,H|KV,D]; v [B,T,KV,Dv] with H % KV == 0 (GQA). Scores
+    are scaled by ``scale``, or 1/sqrt(D)."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     if backend == "reference":
-        return _reference_attention(q, k, v, mode, window)
+        return _reference_attention(q, k, v, mode, window, scale)
     if backend == "chunked":
-        return _chunked_attention(q, k, v, mode, window, chunk)
+        return _chunked_attention(q, k, v, mode, window, chunk, scale)
     if backend == "pallas":
+        if scale != q.shape[-1] ** -0.5 or v.shape[-1] != q.shape[-1]:
+            raise ValueError("the flash kernel takes 1/sqrt(D) and Dv == D")
         from repro.kernels import ops as kops
 
         return kops.flash_attention(q, k, v, causal=(mode != "bidir"),
@@ -157,6 +166,9 @@ def multihead_attention(q, k, v, *, mode: str = "causal",
 def attention_block(params: dict, cfg, sharder, x: jax.Array,
                     positions: jax.Array, *, mode: str,
                     window: Optional[int] = None) -> jax.Array:
+    if cfg.kv_lora_rank:
+        return mla_block(params, cfg, sharder, x, positions, mode=mode,
+                         window=window)
     dt = x.dtype
     wq = sharder.gather(params["wq"].astype(dt), "embed", "heads", None)
     wk = sharder.gather(params["wk"].astype(dt), "embed", "kv_heads", None)
@@ -188,6 +200,8 @@ def cache_specs(cfg, batch: int, max_len: int, *, window: Optional[int]) -> dict
     """Per-layer KV cache specs. ``window`` bounds the buffer (ring) for
     SWA/local attention; full attention stores max_len."""
     W = min(window, max_len) if window else max_len
+    if cfg.kv_lora_rank:
+        return mla_cache_specs(cfg, batch, W)
     KV, hd = cfg.n_kv_heads, cfg.hd
     return {
         "k": ParamSpec((batch, W, KV, hd), ("kv_batch", "kv_seq", "kv_heads", None),
@@ -198,6 +212,17 @@ def cache_specs(cfg, batch: int, max_len: int, *, window: Optional[int]) -> dict
         "pos": ParamSpec((batch, W), ("kv_batch", "kv_seq"),
                          init="const", scale=-1, dtype="int32"),
     }
+
+
+def _put(buf, new, layer, hit):
+    """Layer ``layer`` of the stacked ``buf`` [L,B,W,...] with ``new``
+    [B,1,...] at each row's slot (``hit`` [B,W]), and ``buf`` with that
+    layer written back: a select and one dynamic update, in place where
+    ``buf`` is donated."""
+    old = jax.lax.dynamic_index_in_dim(buf, layer, keepdims=False)
+    sel = hit.reshape(hit.shape + (1,) * (old.ndim - 2))
+    cur = jnp.where(sel, new.astype(buf.dtype), old)
+    return cur, jax.lax.dynamic_update_index_in_dim(buf, cur, layer, 0)
 
 
 def attention_decode(params: dict, cfg, sharder, x: jax.Array,
@@ -215,6 +240,8 @@ def attention_decode(params: dict, cfg, sharder, x: jax.Array,
     put back with a dynamic update, not a scatter: a scatter wants the
     head dim minor, and XLA would relayout the cache around it.
     """
+    if cfg.kv_lora_rank:
+        return mla_decode(params, cfg, x, cache, layer, positions)
     dt = x.dtype
     B = x.shape[0]
     W = cache["k"].shape[2]
@@ -236,18 +263,9 @@ def attention_decode(params: dict, cfg, sharder, x: jax.Array,
 
     positions = pos_t.astype(jnp.int32)
     hit = jnp.arange(W) == (positions % W)[:, None]  # [B, W]
-
-    def put(buf, new):
-        """Layer ``layer`` of ``buf`` with ``new`` [B,1,...] at its slot,
-        and ``buf`` with that layer written back."""
-        old = jax.lax.dynamic_index_in_dim(buf, layer, keepdims=False)
-        sel = hit.reshape(hit.shape + (1,) * (old.ndim - 2))
-        cur = jnp.where(sel, new.astype(buf.dtype), old)
-        return cur, jax.lax.dynamic_update_index_in_dim(buf, cur, layer, 0)
-
-    k_cache, k_all = put(cache["k"], k)
-    v_cache, v_all = put(cache["v"], v)
-    pos_cache, pos_all = put(cache["pos"], positions[:, None])
+    k_cache, k_all = _put(cache["k"], k, layer, hit)
+    v_cache, v_all = _put(cache["v"], v, layer, hit)
+    pos_cache, pos_all = _put(cache["pos"], positions[:, None], layer, hit)
 
     D = q.shape[-1]
     KV = k_cache.shape[2]
@@ -263,3 +281,138 @@ def attention_decode(params: dict, cfg, sharder, x: jax.Array,
     o = o.reshape(B, 1, q.shape[2], D).astype(dt)
     y = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(dt))
     return y, {"k": k_all, "v": v_all, "pos": pos_all}
+
+
+# --------------------------------------------------------------------------- #
+# multi-head latent attention (MLA, deepseek-v2; arXiv:2405.04434 §2.1)
+# --------------------------------------------------------------------------- #
+def mla_specs(cfg) -> dict:
+    """No query compression (deepseek-v2-lite): ``wq`` [d,H,nope+rope];
+    ``wkv_a`` [d, kv_lora_rank + rope] (the latent and the one rope key
+    shared by every head); ``kv_a_norm`` on the latent; ``wkv_b``
+    [kv_lora_rank, H, nope + v] (W_UK then W_UV); ``wo`` [H, v, d]."""
+    d, H, C = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    N, R, V = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq": ParamSpec((d, H, N + R), ("embed", "heads", "head_dim"),
+                        init="fan_in", scale=math.sqrt(H / d)),
+        "wkv_a": ParamSpec((d, C + R), ("embed", None), init="fan_in"),
+        "kv_a_norm": ParamSpec((C,), (None,), init="ones"),
+        "wkv_b": ParamSpec((C, H, N + V), (None, "heads", "head_dim"),
+                           init="fan_in", scale=math.sqrt(H / C)),
+        "wo": ParamSpec((H, V, d), ("heads", "head_dim", "embed"),
+                        init="fan_in", scale=1.0 / math.sqrt(H)),
+    }
+
+
+def mla_cache_specs(cfg, batch: int, W: int) -> dict:
+    """Per layer, the normed latent ``c_kv`` [B,W,kv_lora_rank] and the
+    roped shared key ``k_pe`` [B,W,rope]: kv_lora_rank + rope values per
+    token and layer, whatever the head count."""
+    axes = ("kv_batch", "kv_seq", None)
+    return {
+        "c_kv": ParamSpec((batch, W, cfg.kv_lora_rank), axes, init="zeros",
+                          dtype=cfg.compute_dtype),
+        "k_pe": ParamSpec((batch, W, cfg.qk_rope_head_dim), axes,
+                          init="zeros", dtype=cfg.compute_dtype),
+        "pos": ParamSpec((batch, W), ("kv_batch", "kv_seq"),
+                         init="const", scale=-1, dtype="int32"),
+    }
+
+
+def _mla_rope(cfg):
+    """(frequencies [rope/2], rope amplitude, softmax scale). YaRN scales
+    cos/sin by mscale(factor, yarn_mscale) / mscale(factor,
+    yarn_mscale_all_dim) and the scores by mscale(factor,
+    yarn_mscale_all_dim)^2 / sqrt(nope + rope)."""
+    R = cfg.qk_rope_head_dim
+    scale = (cfg.qk_nope_head_dim + R) ** -0.5
+    if not cfg.rope_scaling_factor:
+        return None, 1.0, scale
+    f = cfg.rope_scaling_factor
+    freqs = yarn_freqs(R, cfg.rope_theta, f, cfg.rope_original_max_position,
+                       cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    m_all = yarn_mscale(f, cfg.yarn_mscale_all_dim)
+    return freqs, yarn_mscale(f, cfg.yarn_mscale) / m_all, scale * m_all ** 2
+
+
+def _mla_project(params, cfg, x, positions):
+    """x [B,S,d] -> q_nope [B,S,H,nope], q_pe [B,S,H,rope], the normed
+    latent c [B,S,C] and the shared k_pe [B,S,rope], roped."""
+    dt = x.dtype
+    C, N = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    freqs, amp, _ = _mla_rope(cfg)
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dt))
+    kv = jnp.einsum("bsd,dk->bsk", x, params["wkv_a"].astype(dt))
+    c = rmsnorm(kv[..., :C], params["kv_a_norm"], cfg.norm_eps)
+    q_pe = apply_rope(q[..., N:], positions, cfg.rope_theta, freqs=freqs)
+    k_pe = apply_rope(kv[:, :, None, C:], positions, cfg.rope_theta,
+                      freqs=freqs)[:, :, 0]
+    if amp != 1.0:
+        q_pe, k_pe = q_pe * amp, k_pe * amp
+    return q[..., :N], q_pe, c, k_pe
+
+
+def mla_block(params: dict, cfg, sharder, x: jax.Array, positions, *,
+              mode: str, window: Optional[int] = None) -> jax.Array:
+    """Full-sequence MLA, not absorbed: keys and values decompressed from
+    the latent, the shared rope key broadcast over the heads."""
+    dt = x.dtype
+    B, S, _ = x.shape
+    H, N = cfg.n_heads, cfg.qk_nope_head_dim
+    q_nope, q_pe, c, k_pe = _mla_project(params, cfg, x, positions)
+    kv = jnp.einsum("bsc,chk->bshk", c, params["wkv_b"].astype(dt))
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    k = jnp.concatenate(
+        [kv[..., :N], jnp.broadcast_to(k_pe[:, :, None], (B, S, H,
+                                                          k_pe.shape[-1]))],
+        -1)
+    q = sharder.constrain(q, "act_batch", None, "act_heads", None)
+    o = multihead_attention(q, k, kv[..., N:], mode=mode, window=window,
+                            backend=cfg.attn_backend, chunk=cfg.attn_chunk,
+                            scale=_mla_rope(cfg)[2])
+    o = sharder.constrain(o, "act_batch", None, "act_heads", None)
+    return jnp.einsum("bshv,hvd->bsd", o, params["wo"].astype(dt))
+
+
+def mla_decode(params: dict, cfg, x: jax.Array, cache: dict,
+               layer: jax.Array, positions: jax.Array
+               ) -> tuple[jax.Array, dict]:
+    """One token per row against the latent cache, with W_UK absorbed into
+    the query and W_UV applied after the weighted sum:
+
+        q_lat = q_nope W_UK                          [B,H,C]
+        s     = (q_lat . c_kv + q_pe . k_pe) * scale [B,H,W]
+        o     = (softmax(s) . c_kv) W_UV             [B,H,v]
+
+    ``cache`` is the whole stack ([L,B,W,C] ``c_kv``, [L,B,W,rope]
+    ``k_pe``, [L,B,W] ``pos``); the new slot of layer ``layer`` lands in
+    place as ``attention_decode``'s does."""
+    dt = x.dtype
+    f32 = jnp.float32
+    W = cache["c_kv"].shape[2]
+    N = cfg.qk_nope_head_dim
+    positions = positions.astype(jnp.int32)
+    with jax.named_scope("mla.project"):
+        q_nope, q_pe, c, k_pe = _mla_project(params, cfg, x,
+                                             positions[:, None])
+    with jax.named_scope("mla.cache_write"):
+        hit = jnp.arange(W) == (positions % W)[:, None]  # [B, W]
+        c_cache, c_all = _put(cache["c_kv"], c, layer, hit)
+        r_cache, r_all = _put(cache["k_pe"], k_pe, layer, hit)
+        pos_cache, pos_all = _put(cache["pos"], positions[:, None], layer,
+                                  hit)
+    with jax.named_scope("mla.attend"):
+        wkv_b = params["wkv_b"].astype(f32)
+        q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(f32),
+                           wkv_b[..., :N])
+        s = (jnp.einsum("bhc,bwc->bhw", q_lat, c_cache.astype(f32))
+             + jnp.einsum("bhr,bwr->bhw", q_pe[:, 0].astype(f32),
+                          r_cache.astype(f32))) * _mla_rope(cfg)[2]
+        valid = (pos_cache >= 0) & (pos_cache <= positions[:, None])
+        s = jnp.where(valid[:, None, :], s, _NEG)
+        p = jax.nn.softmax(s, axis=-1)
+        o_lat = jnp.einsum("bhw,bwc->bhc", p, c_cache.astype(f32))
+        o = jnp.einsum("bhc,chv->bhv", o_lat, wkv_b[..., N:]).astype(dt)
+        y = jnp.einsum("bhv,hvd->bd", o, params["wo"].astype(dt))[:, None]
+    return y, {"c_kv": c_all, "k_pe": r_all, "pos": pos_all}

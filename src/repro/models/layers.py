@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -55,17 +56,49 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
+def yarn_freqs(head_dim: int, theta: float, factor: float,
+               original_max_position: int, beta_fast: float,
+               beta_slow: float) -> jax.Array:
+    """YaRN's frequencies (arXiv:2309.00071, as DeepSeek-V2 publishes
+    them): each pair's frequency blends the original ``1/theta^(2i/D)``
+    and the interpolated ``1/(factor theta^(2i/D))`` by a linear ramp
+    between the pairs that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_max_position``. [D/2]"""
+    def pair(turns):  # the pair index that turns ``turns`` times
+        return (head_dim * math.log(original_max_position
+                                    / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair(beta_fast)), 0)
+    high = min(math.ceil(pair(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = rope_freqs(head_dim, theta)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 mscale ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 def apply_rope(
     x: jax.Array,              # [B, S, H, D]
     positions: jax.Array,      # [B, S] int32  or  [3, B, S] for M-RoPE
     theta: float,
     mrope_sections: Optional[tuple[int, ...]] = None,
+    *,
+    freqs: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Rotary embedding. With ``mrope_sections`` (in *pair* units summing to
-    D/2), frequency bands are driven by the (temporal, h, w) position streams
-    of qwen2-vl's multimodal RoPE."""
+    """Rotary embedding, the halves of D rotated as pairs. With
+    ``mrope_sections`` (in *pair* units summing to D/2), frequency bands
+    are driven by the (temporal, h, w) position streams of qwen2-vl's
+    multimodal RoPE. ``freqs`` [D/2] replaces ``theta``'s (YaRN)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # [D/2]
+    if freqs is None:
+        freqs = rope_freqs(d, theta)  # [D/2]
     if mrope_sections is None:
         if positions.ndim == 3:
             positions = positions[0]

@@ -15,6 +15,11 @@ Design (GShard-style, TPU-native):
   weights (grok: 8 experts on a 16-way axis).
 * optional shared experts (deepseek: 2 shared + 64 routed top-6).
 * load-balancing aux loss (Switch/GShard form) + router z-loss.
+* an expert share (``cfg.expert_parallel`` chips split the experts): the
+  router keeps all ``n_experts`` outputs, this chip holds and computes
+  ``cfg.experts_held`` of them, and a pick of another chip's expert adds
+  nothing here. The serve path of such a layer is ``moe_share_decode``:
+  dropless, with its picks counted (``holds_share``).
 """
 
 from __future__ import annotations
@@ -30,12 +35,16 @@ from repro.models.layers import mlp, mlp_specs
 
 def moe_specs(cfg) -> dict:
     d, E, ff = cfg.d_model, cfg.n_experts, cfg.expert_d_ff
+    held = cfg.experts_held
     specs = {
         "router": ParamSpec((d, E), ("embed", "experts"), init="fan_in",
                             dtype="float32"),
-        "wg": ParamSpec((E, d, ff), ("experts", "embed", "mlp"), init="fan_in"),
-        "wu": ParamSpec((E, d, ff), ("experts", "embed", "mlp"), init="fan_in"),
-        "wd": ParamSpec((E, ff, d), ("experts", "mlp", "embed"), init="fan_in"),
+        "wg": ParamSpec((held, d, ff), ("experts", "embed", "mlp"),
+                        init="fan_in"),
+        "wu": ParamSpec((held, d, ff), ("experts", "embed", "mlp"),
+                        init="fan_in"),
+        "wd": ParamSpec((held, ff, d), ("experts", "mlp", "embed"),
+                        init="fan_in"),
     }
     if cfg.n_shared_experts:
         specs["shared"] = mlp_specs(d, cfg.n_shared_experts * ff, "silu")
@@ -47,31 +56,51 @@ def _capacity(tokens_per_row: int, n_experts: int, top_k: int, cf: float) -> int
     return max(4, min(c, tokens_per_row * top_k))
 
 
+def _route(params, cfg, x):
+    """Router logits, probabilities and the top-k picks (fp32): ``gates``
+    and ``eidx`` [..., K], the gates renormalised only where
+    ``cfg.norm_topk_prob``."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                        params["router"].astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, eidx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9
+        )
+    return logits, probs, gate_vals, eidx
+
+
 def moe_block(params: dict, cfg, sharder, x: jax.Array,
               *, impl: str = "scatter") -> tuple[jax.Array, dict]:
-    """x: [B, S, d] -> (y [B, S, d], aux losses)."""
+    """x: [B, S, d] -> (y [B, S, d], aux losses). With an expert share,
+    the held experts (ids 0 .. experts_held-1) take their picks within the
+    capacity; picks of other experts take no capacity and add nothing."""
     B, S, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    C = _capacity(S, E, K, cfg.capacity_factor)
+    E_all, K = cfg.n_experts, cfg.top_k
+    E = cfg.experts_held
+    share = holds_share(cfg)
+    C = _capacity(S, E_all, K, cfg.capacity_factor)
     dt = x.dtype
 
     # ---- routing (fp32) ------------------------------------------------- #
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
-                        params["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, eidx = jax.lax.top_k(probs, K)          # [B,S,K]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9
-    )
+    logits, probs, gate_vals, eidx = _route(params, cfg, x)  # [B,S,K]
 
     # ---- aux losses ------------------------------------------------------ #
     me = probs.mean(axis=(0, 1))                        # [E] mean router prob
-    ce = jnp.zeros((E,), jnp.float32)
+    ce = jnp.zeros((E_all,), jnp.float32)
     for j in range(K):
-        ce = ce + jax.nn.one_hot(eidx[..., j], E, dtype=jnp.float32).mean((0, 1))
+        ce = ce + jax.nn.one_hot(eidx[..., j], E_all,
+                                 dtype=jnp.float32).mean((0, 1))
     ce = ce / K
-    aux_loss = cfg.moe_aux_loss * E * jnp.sum(me * ce)
+    aux_loss = cfg.moe_aux_loss * E_all * jnp.sum(me * ce)
     z_loss = 1e-3 * jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+
+    if share:
+        # a pick of an expert not held here becomes the id E, which
+        # one_hot makes a zero row: it takes no capacity
+        mine = eidx < E
+        eidx = jnp.where(mine, eidx, E)
 
     # ---- position-in-expert (per row: cumsums stay shard-local) ---------- #
     pos_list, keep_list = [], []
@@ -80,9 +109,11 @@ def moe_block(params: dict, cfg, sharder, x: jax.Array,
         oh = jax.nn.one_hot(eidx[..., j], E, dtype=jnp.int32)      # [B,S,E]
         pos_full = jnp.cumsum(oh, axis=1) - oh + counts[:, None, :]
         pos = jnp.take_along_axis(
-            pos_full, eidx[..., j][..., None], axis=-1
+            pos_full, jnp.minimum(eidx[..., j], E - 1)[..., None], axis=-1
         )[..., 0]                                                   # [B,S]
         keep = pos < C
+        if share:
+            keep = keep & mine[..., j]
         pos_list.append(pos)
         keep_list.append(keep)
         counts = counts + oh.sum(axis=1)
@@ -153,3 +184,57 @@ def moe_block(params: dict, cfg, sharder, x: jax.Array,
         y = y + mlp(params["shared"], x, "silu", sharder)
 
     return y, {"moe_aux": aux_loss, "moe_z": z_loss}
+
+
+#: what ``moe_share_decode`` counts, per step
+COUNTS = ("expert_picks", "expert_peak_picks")
+
+
+def holds_share(cfg) -> bool:
+    """Whether this chip holds only part of each layer's routed experts.
+    Its decode then runs ``moe_share_decode``, dropless and counted; a
+    chip holding every expert decodes through ``moe_block``'s capacity
+    layer, as it always has."""
+    return cfg.experts_held < cfg.n_experts
+
+
+def moe_share_decode(params: dict, cfg, x: jax.Array, *,
+                     rows: jax.Array | None = None
+                     ) -> tuple[jax.Array, dict]:
+    """The serve path of an expert share, dropless: x [B, S, d] (the
+    batch's tokens, one group) -> (y [B, S, d], counts). The share is
+    experts ``0 .. experts_held - 1`` of the router's. ``rows`` [B * S]
+    marks the tokens whose picks are counted (None: every token); it
+    changes no output.
+
+    The router picks over all ``n_experts``; each held expert's capacity
+    is every token of the group (a token picks an expert at most once), so
+    its slots are the tokens themselves, in order, with no dispatch: it
+    computes each token and weights it by the token's gate for it, 0
+    where the token did not pick it. Shared experts run on every token.
+
+    ``counts``: ``expert_picks``, the counted tokens' picks landing on
+    the held experts, and ``expert_peak_picks``, those of the busiest held
+    expert (int32 scalars)."""
+    B, S, d = x.shape
+    E = cfg.experts_held
+    dt = x.dtype
+    xt = x.reshape(B * S, d)
+    with jax.named_scope("moe.route"):
+        _, _, gate_vals, eidx = _route(params, cfg, xt)          # [N,K]
+        picked = eidx[..., None] == jnp.arange(E)                # [N,K,E]
+        w = jnp.where(picked, gate_vals[..., None], 0.0).sum(1)  # [N,E]
+        counted = picked if rows is None else picked & rows[:, None, None]
+        per_expert = counted.sum((0, 1), dtype=jnp.int32)        # [E]
+        counts = {"expert_picks": per_expert.sum(),
+                  "expert_peak_picks": per_expert.max()}
+    with jax.named_scope("moe.experts"):
+        g = jnp.einsum("nd,edf->enf", xt, params["wg"].astype(dt))
+        u = jnp.einsum("nd,edf->enf", xt, params["wu"].astype(dt))
+        out = jnp.einsum("enf,efd->end", jax.nn.silu(g) * u,
+                         params["wd"].astype(dt))
+        y = jnp.einsum("end,ne->nd", out, w.astype(dt)).reshape(B, S, d)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe.shared"):
+            y = y + mlp(params["shared"], x, "silu")
+    return y, counts

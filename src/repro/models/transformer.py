@@ -7,7 +7,8 @@ any depth) with a configurable remat policy.
 Families:
   dense  — [ln → GQA attn → +res] [ln → (SwiGLU|GeLU) MLP → +res]
   moe    — dense block with the FFN replaced by the MoE layer
-           (+ optional leading dense layers: deepseek first_k_dense)
+           (+ optional leading dense layers: deepseek first_k_dense;
+           latent attention and a latent cache where cfg.kv_lora_rank)
   ssm    — [ln → mamba2 mixer → +res]
   hybrid — Griffin pattern (rec, rec, local-attn) scanned as superblocks
            + unrolled remainder blocks; every temporal block is followed
@@ -333,9 +334,23 @@ class LM:
             }
         raise ValueError(fam)
 
-    def decode_step(self, params, cache, tokens, positions, sharder):
+    @property
+    def step_counts(self) -> tuple:
+        """The counts ``decode_step`` returns: an expert share's
+        (``moe.COUNTS``), none for any other model."""
+        cfg = self.cfg
+        if cfg.family == "moe" and moe_mod.holds_share(cfg):
+            return moe_mod.COUNTS
+        return ()
+
+    def decode_step(self, params, cache, tokens, positions, sharder,
+                    rows=None):
         """One token for every row. tokens [B] (or embeds [B,1,Din]);
-        positions [B] (or [3,B] for vlm). Returns (logits [B,V], cache)."""
+        positions [B] (or [3,B] for vlm); ``rows`` [B] bool, the rows in
+        use, which only the counts read (None: every row). Returns
+        (logits [B,V], cache, counts): ``counts`` holds this step's
+        ``step_counts`` (int32 scalars summed over the expert layers), and
+        is empty for a model without any."""
         cfg = self.cfg
         self._sharder = sharder
         cdt = jnp.dtype(cfg.compute_dtype)
@@ -346,16 +361,16 @@ class LM:
 
         fam = cfg.family
         if fam in ("dense", "vlm"):
-            x, c = self._attn_decode_scan(params["layers"], cache["layers"],
-                                          x, positions)
+            x, c, _ = self._attn_decode_scan(params["layers"],
+                                             cache["layers"], x, positions)
             new_cache = {"layers": c}
         elif fam == "moe":
             new_cache = {}
             if cfg.first_k_dense:
-                x, new_cache["dense_layers"] = self._attn_decode_scan(
+                x, new_cache["dense_layers"], _ = self._attn_decode_scan(
                     params["dense_layers"], cache["dense_layers"], x, positions)
-            x, new_cache["layers"] = self._attn_decode_scan(
-                params["layers"], cache["layers"], x, positions)
+            x, new_cache["layers"], counts = self._attn_decode_scan(
+                params["layers"], cache["layers"], x, positions, rows)
         elif fam == "ssm":
             def body_s(carry, xs):
                 p, c = xs
@@ -373,7 +388,7 @@ class LM:
                 p, c = xs
                 y, c1 = self._rec_decode_block(p["rec1"], c["rec1"], y)
                 y, c2 = self._rec_decode_block(p["rec2"], c["rec2"], y)
-                y, ca = self._attn_decode_block(
+                y, ca, _ = self._attn_decode_block(
                     p["attn"], ca, i, y, positions, window=cfg.local_window
                 )
                 return (y, ca, i + 1), {"rec1": c1, "rec2": c2}
@@ -395,24 +410,27 @@ class LM:
             raise ValueError(fam)
 
         logits = self._logits_out(params, x, sharder)[:, 0]
-        return logits, new_cache
+        return logits, new_cache, counts if fam == "moe" else {}
 
     # decode block helpers ------------------------------------------------- #
-    def _attn_decode_scan(self, params, cache, x, positions):
+    def _attn_decode_scan(self, params, cache, x, positions, rows=None):
         """Decode through a stack of attention blocks. The stacked cache
         and the layer index ride in the scan's carry, so each block
         writes its slot in place and the cache stays one buffer in one
-        layout; only the parameters are scanned over."""
+        layout; only the parameters are scanned over. Returns (x, cache,
+        the expert share's counts summed over the layers, or {})."""
         def body(carry, p):
             y, c, i = carry
-            y, c = self._attn_decode_block(p, c, i, y, positions)
-            return (y, c, i + 1), None
+            y, c, n = self._attn_decode_block(p, c, i, y, positions,
+                                              rows=rows)
+            return (y, c, i + 1), n
 
-        (x, cache, _), _ = _maybe_scan(
+        (x, cache, _), counts = _maybe_scan(
             self.cfg, body, (x, cache, jnp.int32(0)), params)
-        return x, cache
+        return x, cache, jax.tree_util.tree_map(lambda a: a.sum(0), counts)
 
-    def _attn_decode_block(self, p, c, layer, x, positions, *, window=None):
+    def _attn_decode_block(self, p, c, layer, x, positions, *, window=None,
+                           rows=None):
         cfg = self.cfg
         win = window if window is not None else cfg.swa_window
         pos_b = positions if positions.ndim == 1 else positions[0]
@@ -424,17 +442,23 @@ class LM:
         )
         x = x + h
         h = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
+        counts = {}
         if "mlp" in p:
             h = L.mlp(p["mlp"], h, cfg.mlp_act, sharder)
         else:
             # decode-time MoE: route the whole batch as ONE group ([B,1,d]
             # -> [1,B,d]) so expert capacity is shared across rows instead
             # of a per-row floor — removes the ~30x dead-slot compute of
-            # per-row capacity at S=1 (§Perf iteration H).
+            # per-row capacity at S=1 (§Perf iteration H). A chip holding
+            # a share of the experts serves the group dropless.
             hh = jnp.swapaxes(h, 0, 1)
-            hh, _ = moe_mod.moe_block(p["moe"], cfg, sharder, hh)
+            if moe_mod.holds_share(cfg):
+                hh, counts = moe_mod.moe_share_decode(p["moe"], cfg, hh,
+                                                      rows=rows)
+            else:
+                hh, _ = moe_mod.moe_block(p["moe"], cfg, sharder, hh)
             h = jnp.swapaxes(hh, 0, 1)
-        return x + h, c2
+        return x + h, c2, counts
 
     def _rec_decode_block(self, p, c, x):
         cfg = self.cfg

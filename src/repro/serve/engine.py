@@ -109,8 +109,13 @@ class InferenceServer:
         self.sharder = Sharder(None)
         self.params = init_tree(jax.random.PRNGKey(seed),
                                 self.model.param_specs(), cfg.param_dtype)
-        self._step = jax.jit(make_serve_step(self.model, self.sharder),
-                             donate_argnums=(1,))
+        self._step = self._loop_step(jax.jit(
+            make_serve_step(self.model, self.sharder), donate_argnums=(1,)))
+        #: the rows in use (a [max_batch] bool array), handed to a step
+        #: that counts (``LM.step_counts``); None for any other model
+        self._rows = None
+        #: the last step's counts, for the fetch
+        self._step_counts: dict = {}
         # every decode dispatch is a preemption point: a broker revoke or
         # elastic shrink parks this worker within ~one engine step even
         # when the batch never drains (docs/PREEMPTION.md tier 3)
@@ -131,6 +136,18 @@ class InferenceServer:
         """A snapshot of the decode loop's counters
         (``repro.trace.serve_obs.ServeCounters``)."""
         return self._counters.as_dict()
+
+    def _loop_step(self, step):
+        """The loop's step, ``(params, cache, tokens, positions) ->
+        (logits, cache)``, around the jitted serve step: it hands the step
+        ``self._rows`` and keeps the counts the step returns in
+        ``self._step_counts``."""
+        def loop_step(params, cache, tokens, positions):
+            logits, cache, self._step_counts = step(params, cache, tokens,
+                                                    positions, self._rows)
+            return logits, cache
+
+        return loop_step
 
     # ------------------------------------------------------------------ #
     def submit(self, req: Request) -> Request:
@@ -236,6 +253,7 @@ class InferenceServer:
         span = jax.profiler.TraceAnnotation
         cache, _, _ = make_decode_inputs(cfg, B, self.max_len,
                                          jax.random.PRNGKey(1))
+        counted = bool(self.model.step_counts)
         active: list[Optional[Request]] = [None] * B
         pos = np.zeros(B, np.int64)
         remaining = np.zeros(B, np.int64)
@@ -287,6 +305,8 @@ class InferenceServer:
                 p = jnp.asarray(pos, jnp.int32)
                 if cfg.mrope_sections is not None:
                     p = jnp.broadcast_to(p, (3, B))
+                if counted:
+                    self._rows = jnp.asarray([a is not None for a in active])
                 logits, cache = self._step(self.params, cache, toks, p)
             t3 = clock()
             with span(obs.DEVICE_WAIT):
@@ -295,7 +315,12 @@ class InferenceServer:
                 logits.block_until_ready()
             t4 = clock()
             with span(obs.FETCH):
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+                if counted:
+                    nxt, got = jax.device_get(
+                        (jnp.argmax(logits, axis=-1), self._step_counts))
+                    c.add_step_counts(got)
+                else:
+                    nxt = np.asarray(jnp.argmax(logits, axis=-1))
             t5 = clock()
             c.steps += 1
             c.rows += rows

@@ -15,9 +15,9 @@ phase itself:
 - ``serve.device_wait``: ``block_until_ready`` on the step's logits. The
   device runs while the worker keeps its USF slot: the scheduler does not
   intercept this wait;
-- ``serve.fetch``: the argmax and its copy to the host. The argmax is a
-  device program of its own: where servers share a chip it queues behind
-  another server's step;
+- ``serve.fetch``: the argmax and its copy to the host, with the step's
+  own counts where it has any. The argmax is a device program of its own:
+  where servers share a chip it queues behind another server's step;
 - ``serve.bookkeep``: advancing rows, appending tokens, retiring requests
   (``finished``).
 
@@ -26,7 +26,12 @@ slot, blocked or ready: a server idle on its queue, a preemption at the
 dispatch boundary, a co-located job's sleep.
 
 Counters are always on. ``ServeCounters`` are one server's
-(``InferenceServer.stats()``); ``compiles()`` is the process's.
+(``InferenceServer.stats()``); ``compiles()`` is the process's. A model
+whose step returns counts of its own beside the logits (``LM.step_counts``:
+an expert share's ``expert_picks`` and ``expert_peak_picks`` over the rows
+in use, ``models/moe.py``) has them fetched with the argmax and added up
+in ``ServeCounters.step_counts``; they appear in ``stats()`` beside the
+loop's, and for no other model.
 """
 
 from __future__ import annotations
@@ -66,14 +71,21 @@ class ServeCounters:
     device_wait_s: float = 0.0
     fetch_s: float = 0.0
     bookkeep_s: float = 0.0
+    #: the step's own counts (``LM.step_counts``), summed over steps
+    step_counts: dict = dataclasses.field(default_factory=dict)
 
     @property
     def host_s(self) -> float:
         """Loop time outside the device wait and outside parks."""
         return self.admit_s + self.dispatch_s + self.fetch_s + self.bookkeep_s
 
+    def add_step_counts(self, counts: dict) -> None:
+        for k, v in counts.items():
+            self.step_counts[k] = self.step_counts.get(k, 0) + int(v)
+
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)
+        d.update(d.pop("step_counts"))
         d["host_s"] = self.host_s
         return d
 

@@ -139,14 +139,17 @@ def make_prefill_step(model, sharder) -> Callable[[dict, dict], jax.Array]:
 SERVE_STEP = "serve_step"
 
 
-def make_serve_step(model, sharder) -> Callable[..., tuple[jax.Array, dict]]:
-    """One decode token against a KV cache."""
+def make_serve_step(model, sharder
+                    ) -> Callable[..., tuple[jax.Array, dict, dict]]:
+    """One decode token against a KV cache: (logits, cache, counts), as
+    ``LM.decode_step`` returns them."""
 
     def step(params: dict, cache: dict, tokens: jax.Array,
-             positions: jax.Array) -> tuple[jax.Array, dict]:
+             positions: jax.Array, rows: jax.Array | None = None
+             ) -> tuple[jax.Array, dict, dict]:
         with jax.named_scope(SERVE_STEP):
             return model.decode_step(params, cache, tokens, positions,
-                                     sharder)
+                                     sharder, rows)
 
     step.__name__ = step.__qualname__ = SERVE_STEP
     return step

@@ -38,6 +38,18 @@ BENCH_ARCHS = {"smollm_360m": "float32", "h2o_danube_3_4b": "bfloat16"}
 # output: relayouts around the scatter, and a second stacked cache
 SCATTER_TEMP_BYTES = {"smollm_360m": 1_649_926_144,
                       "h2o_danube_3_4b": 2_362_139_648}
+# the dense steps at that shape as compiled before the latent cache and the
+# expert share joined the shared decode code: instructions in the compiled
+# module, and its cost analysis's flops and bytes accessed. A change that
+# means to change the dense step updates these.
+DENSE_STEP = {"smollm_360m": (599, 4188630528.0, 2883678720.0),
+              "h2o_danube_3_4b": (594, 18259816448.0, 864766464.0)}
+# deepseek_v2_lite at the benchmark's shape (bench/configs/
+# deepseek_v2_lite.json): 192 rows of 768 positions, 8 of 64 experts held,
+# bfloat16 weights
+DS_BATCH, DS_LEN, DS_EP = 192, 768, 8
+DS_SCOPES = ("mla.project", "mla.cache_write", "mla.attend", "moe.route",
+             "moe.experts", "moe.shared")
 
 
 @pytest.fixture(scope="module")
@@ -167,6 +179,58 @@ def test_bench_step_temporaries_halved(bench_step):
     arch, _, step = bench_step
     temp = step.memory_analysis().temp_size_in_bytes
     assert temp < SCATTER_TEMP_BYTES[arch] / 2
+
+
+def test_bench_step_is_unchanged(bench_step):
+    """The dense step compiles to what it compiled to before the latent
+    cache and the expert share: same instruction count, same cost."""
+    arch, _, step = bench_step
+    ops_n = sum(1 for ln in step.as_text().splitlines()
+                if " = " in ln and not ln.lstrip().startswith("//"))
+    cost = step.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert (ops_n, cost["flops"], cost["bytes accessed"]) == DENSE_STEP[arch]
+
+
+@pytest.fixture(scope="module")
+def ds_step(chip):
+    """deepseek_v2_lite's donated decode step at the benchmark's shape:
+    (abstract cache, compiled step)."""
+    cfg = dataclasses.replace(get_arch("deepseek_v2_lite"),
+                              expert_parallel=DS_EP, param_dtype="bfloat16")
+    model = build_model(cfg)
+    params = _on(abstract_tree(model.param_specs(), cfg.param_dtype), chip)
+    cache = _on(abstract_tree(model.cache_specs(DS_BATCH, DS_LEN),
+                              cfg.param_dtype), chip)
+    tok = _spec((DS_BATCH,), jnp.int32, chip)
+    rows = _spec((DS_BATCH,), jnp.bool_, chip)  # the rows in use, as served
+    return cache, _compile(make_serve_step(model, Sharder(None)), params,
+                           cache, tok, tok, rows, donate_argnums=(1,))
+
+
+def test_latent_step_fits_and_updates_its_cache_in_place(ds_step):
+    """Weights, latent cache and temporaries fit one chip; the latent
+    cache aliases its outputs and is never copied whole or by layer."""
+    cache, step = ds_step
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    assert step.memory_analysis().alias_size_in_bytes == cache_bytes
+    shapes = {_squeezed(x.shape[i:]) for x in
+              jax.tree_util.tree_leaves(cache["layers"]) for i in (0, 1)}
+    copies = [m.group(0) for m in re.finditer(
+        r"%(\S+) = \w+\[([\d,]*)\]\S* copy\(", step.as_text())
+        if _squeezed(tuple(int(d) for d in m.group(2).split(",") if d))
+        in shapes]
+    assert not copies
+
+
+def test_latent_step_carries_its_named_scopes(ds_step):
+    """The six scopes label the step's operations in the compiled module,
+    which is what a profile's op metadata shows."""
+    _, step = ds_step
+    text = step.as_text()
+    for scope in DS_SCOPES:
+        assert f"/{scope}/" in text, scope
 
 
 def test_reference_forward_compiles(chip, smollm):

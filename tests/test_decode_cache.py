@@ -26,10 +26,12 @@ B, MAX_LEN = 3, 24
 # distinct positions; the last wraps every ring (W is 24, or 16 for the
 # smoke configurations' sliding and local windows)
 POSITIONS = (2, 9, 29)
-# every configuration whose decode cache holds attention layers: dense,
-# sliding window, vlm with M-RoPE, moe with a leading dense layer, hybrid
+# every configuration whose decode cache holds k/v attention layers: dense,
+# sliding window, vlm with M-RoPE, moe with a leading dense layer, hybrid.
+# The latent cache (deepseek_v2_lite) has its own test in test_deepseek_v2.py
 ARCHS = [a for a in list_archs()
-         if get_smoke(a).supports_decode and get_smoke(a).family != "ssm"]
+         if get_smoke(a).supports_decode and get_smoke(a).family != "ssm"
+         and not get_smoke(a).kv_lora_rank]
 # the layer scan, and its Python unroll (``scan_layers=False``, the dry-run's
 # cost probes) for one configuration of each family
 CASES = [(a, True) for a in ARCHS] + [
@@ -131,9 +133,9 @@ def test_decode_writes_one_slot_per_row_and_layer(arch_id, scan_layers,
         return jax.jit(lambda p, c, t, q: model.decode_step(
             p, c, t, q, sharder))(params, cache, tok, pos)
 
-    logits, new = run()
+    logits, new, _ = run()
     monkeypatch.setattr(attn, "attention_decode", _scatter_attention_decode)
-    ref_logits, ref = run()
+    ref_logits, ref, _ = run()
 
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
                                rtol=1e-5, atol=1e-5)

@@ -73,12 +73,12 @@ def test_decode_step(arch_id):
     step = jax.jit(
         lambda p, c, t, po: model.decode_step(p, c, t, po, sharder)
     )
-    logits, cache = step(params, cache, tok, pos)
+    logits, cache, _ = step(params, cache, tok, pos)
     assert logits.shape == (B, cfg.vocab)
     assert np.all(np.isfinite(np.asarray(logits, dtype=np.float32)))
     # advance one more position: cache round-trips through the jitted fn
     pos2 = pos + 1
-    logits2, cache = step(params, cache, tok, pos2)
+    logits2, cache, _ = step(params, cache, tok, pos2)
     assert np.all(np.isfinite(np.asarray(logits2, dtype=np.float32)))
 
 
@@ -117,7 +117,7 @@ def test_decode_matches_prefill(arch_id):
         pos = jnp.full((1,), i, jnp.int32)
         if cfg.mrope_sections is not None:
             pos = jnp.broadcast_to(pos, (3, 1))
-        lg, cache = step(params, cache, tok, pos)
+        lg, cache, _ = step(params, cache, tok, pos)
         outs.append(np.asarray(lg, dtype=np.float32))
     dec = np.stack(outs, axis=1)  # [1,T,V]
     ref = np.asarray(full_logits, dtype=np.float32)
