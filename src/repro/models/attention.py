@@ -225,6 +225,17 @@ def _put(buf, new, layer, hit):
     return cur, jax.lax.dynamic_update_index_in_dim(buf, cur, layer, 0)
 
 
+def _put_slots(buf, new, layer, slot):
+    """``buf`` [L,B,W,...] with ``new`` [B,...] scattered to ``[layer, b,
+    slot[b]]``: B slots written in place where ``buf`` is donated, and
+    nothing else of the layer read or written. The (b, slot) pairs are
+    unique, one per row."""
+    rows = jnp.arange(slot.shape[0])
+    return buf.at[layer, rows, slot].set(new.astype(buf.dtype),
+                                         indices_are_sorted=True,
+                                         unique_indices=True)
+
+
 def attention_decode(params: dict, cfg, sharder, x: jax.Array,
                      cache: dict, layer: jax.Array, positions: jax.Array, *,
                      window: Optional[int] = None) -> tuple[jax.Array, dict]:
@@ -386,8 +397,12 @@ def mla_decode(params: dict, cfg, x: jax.Array, cache: dict,
         o     = (softmax(s) . c_kv) W_UV             [B,H,v]
 
     ``cache`` is the whole stack ([L,B,W,C] ``c_kv``, [L,B,W,rope]
-    ``k_pe``, [L,B,W] ``pos``); the new slot of layer ``layer`` lands in
-    place as ``attention_decode``'s does."""
+    ``k_pe``, [L,B,W] ``pos``). The latent cache stores a slot as one
+    contiguous row, so the B new latents and positions of layer ``layer``
+    are scattered in place at ``[layer, b, pos % W]`` and attention reads
+    the layer from the written stack: no pass over the whole layer, as the
+    dense caches' select needs (``attention_decode``). ``k_pe`` keeps that
+    select."""
     dt = x.dtype
     f32 = jnp.float32
     W = cache["c_kv"].shape[2]
@@ -397,11 +412,16 @@ def mla_decode(params: dict, cfg, x: jax.Array, cache: dict,
         q_nope, q_pe, c, k_pe = _mla_project(params, cfg, x,
                                              positions[:, None])
     with jax.named_scope("mla.cache_write"):
-        hit = jnp.arange(W) == (positions % W)[:, None]  # [B, W]
-        c_cache, c_all = _put(cache["c_kv"], c, layer, hit)
-        r_cache, r_all = _put(cache["k_pe"], k_pe, layer, hit)
-        pos_cache, pos_all = _put(cache["pos"], positions[:, None], layer,
-                                  hit)
+        slot = positions % W
+        c_all = _put_slots(cache["c_kv"], c[:, 0], layer, slot)
+        pos_all = _put_slots(cache["pos"], positions, layer, slot)
+        # k_pe is stored positions minor (its 64 fill half a lane), where
+        # XLA may relayout the buffer around a scatter: it keeps the select
+        r_cache, r_all = _put(cache["k_pe"], k_pe, layer,
+                              jnp.arange(W) == slot[:, None])
+        c_cache, pos_cache = (
+            jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+            for a in (c_all, pos_all))
     with jax.named_scope("mla.attend"):
         wkv_b = params["wkv_b"].astype(f32)
         q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0].astype(f32),

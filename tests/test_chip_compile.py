@@ -48,6 +48,12 @@ DENSE_STEP = {"smollm_360m": (599, 4188630528.0, 2883678720.0),
 # deepseek_v2_lite.json): 192 rows of 768 positions, 8 of 64 experts held,
 # bfloat16 weights
 DS_BATCH, DS_LEN, DS_EP = 192, 768, 8
+# the latent step's cost analysis's bytes accessed when it wrote each layer
+# of the latent cache by a select over the layer and a dynamic update (a
+# compile for the described v5e, before the slot scatter); the scatter
+# takes at least LATENT_WRITE_BYTES_CUT off it
+LATENT_SELECT_STEP_BYTES = 3_195_618_816
+LATENT_WRITE_BYTES_CUT = 0.4e9
 DS_SCOPES = ("mla.project", "mla.cache_write", "mla.attend", "moe.route",
              "moe.experts", "moe.shared")
 
@@ -222,6 +228,24 @@ def test_latent_step_fits_and_updates_its_cache_in_place(ds_step):
         if _squeezed(tuple(int(d) for d in m.group(2).split(",") if d))
         in shapes]
     assert not copies
+
+
+def test_latent_step_writes_slots_not_layers(ds_step):
+    """No select or dynamic update as large as a layer of ``c_kv``, in the
+    scan or in the one-layer stack, and the step's bytes fall with the
+    write-back: the B new slots are scattered into the stack."""
+    cache, step = ds_step
+    layer = _squeezed(cache["layers"]["c_kv"].shape[1:])
+    whole = [m.group(0) for m in re.finditer(
+        r"%(\S+) = \w+\[([\d,]*)\]\S* (select|dynamic-update-slice)\(",
+        step.as_text())
+        if _squeezed(tuple(int(d) for d in m.group(2).split(",") if d))[-3:]
+        == layer]
+    assert not whole
+    cost = step.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < (LATENT_SELECT_STEP_BYTES
+                                     - LATENT_WRITE_BYTES_CUT)
 
 
 def test_latent_step_carries_its_named_scopes(ds_step):

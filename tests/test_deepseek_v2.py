@@ -25,6 +25,7 @@ from repro.core.policies import SchedCoop
 from repro.core.threads import UsfRuntime
 from repro.core.topology import Topology
 from repro.launch.inputs import make_decode_inputs
+from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models.base import init_tree
 from repro.models.layers import mlp, yarn_freqs, yarn_mscale
@@ -202,23 +203,30 @@ def test_counts_are_the_picks_of_held_experts(masked):
     assert int(counts["expert_peak_picks"]) == per.max()
 
 
+def _random_latent_cache(cfg, positions, W, seed):
+    """Both latent stacks of ``W`` slots filled with random latents and
+    rope keys, each row's slots holding the ``W`` positions before its
+    own (-1 where none): (cache, tokens)."""
+    cache, tok, _ = make_decode_inputs(cfg, len(positions), max_len=W,
+                                       key=jax.random.PRNGKey(1))
+    last = np.stack([p - 1 - (p - 1 - np.arange(W)) % W for p in positions])
+    for i, n in enumerate(("dense_layers", "layers")):
+        c = cache[n]
+        k1, k2 = jax.random.split(jax.random.PRNGKey(seed + i))
+        c["c_kv"] = jax.random.normal(k1, c["c_kv"].shape)
+        c["k_pe"] = jax.random.normal(k2, c["k_pe"].shape)
+        c["pos"] = jnp.broadcast_to(jnp.asarray(np.maximum(last, -1)),
+                                    c["pos"].shape)
+    return cache, tok
+
+
 def test_decode_writes_one_latent_slot_per_row_and_layer():
     """One step writes each row's latent, rope key and position at
     ``[layer, b, pos % W]`` of every stacked latent cache, and nothing
     else, bit for bit."""
     cfg, model, params = _setup()
     B, W, positions = 3, 16, (2, 9, 21)
-    cache, tok, _ = make_decode_inputs(cfg, B, max_len=W,
-                                       key=jax.random.PRNGKey(1))
-    for i, n in enumerate(("dense_layers", "layers")):
-        c = cache[n]
-        k1, k2 = jax.random.split(jax.random.PRNGKey(10 + i))
-        c["c_kv"] = jax.random.normal(k1, c["c_kv"].shape)
-        c["k_pe"] = jax.random.normal(k2, c["k_pe"].shape)
-        last = np.stack([p - 1 - (p - 1 - np.arange(W)) % W
-                         for p in positions])
-        c["pos"] = jnp.broadcast_to(jnp.asarray(np.maximum(last, -1)),
-                                    c["pos"].shape)
+    cache, tok = _random_latent_cache(cfg, positions, W, seed=10)
     old = jax.tree_util.tree_map(np.asarray, cache)
     _, new, counts = jax.jit(lambda p, c, t, q: model.decode_step(
         p, c, t, q, Sharder(None)))(params, cache, tok,
@@ -237,6 +245,42 @@ def test_decode_writes_one_latent_slot_per_row_and_layer():
         np.testing.assert_array_equal(
             np.asarray(new[n]["pos"])[hit].reshape(L, B),
             np.broadcast_to(positions, (L, B)))
+
+
+def _put_by_select(buf, new, layer, slot):
+    """The latent cache's former write: a select over the whole layer put
+    back with a dynamic update (``attention._put``)."""
+    hit = jnp.arange(buf.shape[2]) == slot[:, None]
+    return attn_mod._put(buf, new[:, None], layer, hit)[1]
+
+
+@pytest.mark.parametrize("positions", [
+    (2, 9, 13, 5),     # distinct positions
+    (7, 7, 7, 3),      # three rows at one position
+    (16, 21, 31, 40),  # at and past W: the ring
+], ids=["distinct", "same", "ring"])
+def test_slot_scatter_writes_what_the_layer_select_wrote(positions,
+                                                          monkeypatch):
+    """One step from a random latent cache writes ``c_kv``, ``k_pe`` and
+    ``pos`` of both stacks bit for bit as the whole-layer select did, and
+    its logits match within float32 rounding."""
+    cfg, model, params = _setup()
+    cache, tok = _random_latent_cache(cfg, positions, 16, seed=20)
+    pos = jnp.asarray(positions, jnp.int32)
+
+    def step(c):
+        return jax.jit(lambda p, c, t, q: model.decode_step(
+            p, c, t, q, Sharder(None)))(params, c, tok, pos)
+
+    got, new, _ = step(cache)
+    monkeypatch.setattr(attn_mod, "_put_slots", _put_by_select)
+    want, old, _ = step(cache)
+    for n in ("dense_layers", "layers"):
+        for leaf in ("c_kv", "k_pe", "pos"):
+            np.testing.assert_array_equal(np.asarray(new[n][leaf]),
+                                          np.asarray(old[n][leaf]))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=1e-5)
 
 
 #: the smoke decode of the existing expert layers, 4 steps of 4 rows from
